@@ -84,11 +84,11 @@ def run_method(method: str, query, data, *, limit=100_000, step_budget=None,
     if method == "vector":
         # warm measurement: compile plan + jit once (plan-cache hit on the
         # second call), time the warm run — per-plan jit churn is a
-        # shape-bucketing problem, not enumeration cost (EXPERIMENTS.md
-        # §Perf[cemr-engine]). tile_rows balances dead-lane compute against
-        # chunk count: ladder supersteps + frontier packing keep small tiles
-        # utilized, so 512 beats the huge tiles the pre-scheduler host loop
-        # needed to amortize its per-primitive round trips.
+        # shape-bucketing problem, not enumeration cost. tile_rows balances
+        # dead-lane compute against chunk count: ladder supersteps +
+        # frontier packing keep small tiles utilized, so 512 beats the huge
+        # tiles the pre-scheduler host loop needed to amortize its
+        # per-primitive round trips.
         opts = MatchOptions(engine="vector", tile_rows=512, limit=limit)
         # an earlier ref-method pass may have compiled this query under the
         # same plan key; drop it so the cold call measures a true cold

@@ -39,6 +39,8 @@ def main() -> None:
                     default=None, metavar="PATH",
                     help="also write rows to PATH (default BENCH_engine.json)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     scale = 0.08 if args.full else 0.03
 
     benches = {

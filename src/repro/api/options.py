@@ -104,8 +104,10 @@ class MatchOptions:
     intersect       : intersect kernel — "auto" (Pallas compiled on TPU, jnp
                       oracle elsewhere), "pallas" (force the kernel;
                       interpret-mode off-TPU), "jnp", or "fused" (fold the
-                      boundary expand+intersect+popcount into one autotuned
-                      Pallas kernel).
+                      boundary expand+intersect+popcount into one Pallas
+                      launch). Both kernels DMA whole table rows, padded
+                      to 128 lanes, 8 frontier rows per grid step; there
+                      is no width to tune (docs/engine.md §Kernel layout).
     mesh            : multi-device sharded enumeration (vector engine):
                       None = single device (default), "auto" = cost-based
                       (shard across every local device only when

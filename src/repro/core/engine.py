@@ -33,6 +33,7 @@ delegates to it.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -346,8 +347,7 @@ class VectorEngine:
         from repro.kernels import ops as _kops
         pairs = [(s, u, op.vertex) for (s, u) in op.bk_pairs]
         slots = tuple(s for (s, _, _) in pairs)
-        wb = _kops.autotune_words_per_block(len(pairs), op.n_words)
-        fused_fn = _kops.make_fused_expand_intersect_fn(words_per_block=wb)
+        fused_fn = _kops.make_fused_expand_intersect_fn()
         expand = self._make_expand(si, with_sel=True)
         same_slots = list(op.same_label_idx_slots)
 
@@ -510,12 +510,21 @@ class VectorEngine:
                                    materialize=materialize)
 
     # ------------------------------------------------------------ materialize
-    def _materialize(self, tile) -> list[dict[int, int]]:
+    def _materialize(self, tile, cap: int) -> list[dict[int, int]]:
+        """Decode at most `cap` explicit embeddings from a leaf tile. One
+        row's bitmap sets expand to their injective product, which on a
+        large graph outgrows any limit (and the host's memory), so the
+        decoding stops at `cap`."""
+        return list(itertools.islice(self._iter_embeddings(tile),
+                                     max(cap, 0)))
+
+    def _iter_embeddings(self, tile):
         plan = self.plan
         idx = np.asarray(tile["idx"])
         alive = np.asarray(tile["alive"])
-        bm = {u: np.asarray(v) for u, v in tile["bm"].items()}
-        out = []
+        # a host copy of a TPU array can keep a strided layout, and a row
+        # must be contiguous to be viewed as bytes
+        bm = {u: np.ascontiguousarray(v) for u, v in tile["bm"].items()}
         for row in np.nonzero(alive)[0]:
             base = {}
             for k, u in enumerate(plan.idx_slots):
@@ -535,17 +544,16 @@ class VectorEngine:
 
             def rec(gi, acc):
                 if gi == len(group_list):
-                    out.append(dict(acc))
+                    yield dict(acc)
                     return
                 us = group_list[gi]
                 for combo in iter_injective([sets[u] for u in us]):
                     acc2 = dict(acc)
                     for u, v in zip(us, combo):
                         acc2[u] = int(v)
-                    rec(gi + 1, acc2)
+                    yield from rec(gi + 1, acc2)
 
-            rec(0, base)
-        return out
+            yield from rec(0, base)
 
 
 def vector_match(query: Graph, data: Graph, *, encoding: str = "cost",

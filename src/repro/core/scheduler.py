@@ -64,7 +64,6 @@ from collections import OrderedDict
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from . import bitops
 from .engine import VectorMatchResult, VectorStats
@@ -153,7 +152,8 @@ def _leaf_products(n_singles, group_sizes):
 
 def make_leaf_reduce(leaf_singles, leaf_groups):
     """Device leaf reduction: (terms (T, n) int32, alive (T,) bool) ->
-    (count () int64, overflow () bool). Must be traced under enable_x64()."""
+    (count () int64, overflow () bool). Must be traced under
+    jax.enable_x64(True)."""
     products = _leaf_products(len(leaf_singles), [len(g) for g in leaf_groups])
 
     def reduce(terms, alive):
@@ -170,7 +170,7 @@ def make_leaf_reduce_batched(leaf_singles, leaf_groups, n_queries):
     """Superbatch leaf reduction with a query-id lane:
     (terms (T, n) int32, alive (T,) bool, qid (T,) int32) ->
     (count (Q,) int64 segment-summed per query, overflow (Q,) bool).
-    Must be traced under enable_x64()."""
+    Must be traced under jax.enable_x64(True)."""
     products = _leaf_products(len(leaf_singles), [len(g) for g in leaf_groups])
 
     def reduce(terms, alive, qid):
@@ -746,7 +746,7 @@ class TileScheduler:
             self._superstep(b)
         bufs = {si: self._buffers[si] for si in seg_cer}
         fbufs = {si: self._fail_buffers[si] for si in seg_fail}
-        with enable_x64():                           # leaf reduce is int64
+        with jax.enable_x64(True):                   # leaf reduce is int64
             (leaf_tile, terms, cnt, ovf, packed, frontiers, bufs2,
              fbufs2) = fn(tile, r, jnp.int32(cursor), bufs, fbufs,
                           eng.tables, eng.masks)
@@ -769,7 +769,8 @@ class TileScheduler:
                 "frontiers": frontiers, "sync": (packed, cnt, ovf),
                 "np": None}
 
-    def _process_fused(self, p, stack, pending, embeddings, materialize):
+    def _process_fused(self, p, stack, pending, embeddings, materialize,
+                       limit):
         """Apply one synced readback: fold the packed tail counters, resume
         the root chunk cursor (the only item whose total is unknown at
         dispatch), walk the ladder routing the first overflowing frontier,
@@ -817,7 +818,8 @@ class TileScheduler:
         else:
             c = int(cnt_np)
         if materialize and c:
-            embeddings.extend(eng._materialize(p["leaf_tile"]))
+            embeddings.extend(eng._materialize(p["leaf_tile"],
+                                               limit - len(embeddings)))
         return c
 
     def _run_fused(self, *, limit, max_steps, materialize):
@@ -868,7 +870,7 @@ class TileScheduler:
                 _sync_inflight(st, inflight)
             for p in inflight:
                 count += self._process_fused(p, stack, pending, embeddings,
-                                             materialize)
+                                             materialize, limit)
                 if count >= limit:
                     break
             if count >= limit:
@@ -894,7 +896,7 @@ class TileScheduler:
         eng = self.eng
         terms, alive = eng._leaf_fn()(tile)
         st.device_steps += 1
-        with enable_x64():
+        with jax.enable_x64(True):
             cnt, ovf = self._leaf_reduce_fn()(terms, alive)
         st.device_steps += 1
         if bool(jax.device_get(ovf)):
@@ -934,7 +936,8 @@ class TileScheduler:
                     st.leaf_tiles += 1
                     c = self._leaf_count(tile)
                     if materialize and c:
-                        embeddings.extend(eng._materialize(tile))
+                        embeddings.extend(eng._materialize(
+                            tile, limit - len(embeddings)))
                     count += c
                     if count >= limit:
                         break
@@ -1580,7 +1583,7 @@ class SuperbatchScheduler:
                 prog.superstep(b)
             bufs = {si: self._buffers[si] for si in seg_cer}
             fbufs = {si: self._fail_buffers[si] for si in seg_fail}
-            with enable_x64():                       # leaf reduce is int64
+            with jax.enable_x64(True):               # leaf reduce is int64
                 (leaf_tile, terms, cnt_q, ovf_q, packed, frontiers, bufs2,
                  fbufs2) = fn(tile, r, jnp.int32(cursor), bufs, fbufs,
                               self.data, active)

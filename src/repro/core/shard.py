@@ -53,8 +53,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.distributed.sharding import partition_bitmap
@@ -174,11 +172,11 @@ class _ShardLoopBase:
                     packed[None], ex(frontiers), ex(bufs2), ex(fbufs2),
                     total)
 
-        fn = jax.jit(shard_map(
-            body, self.mesh,
+        fn = jax.jit(jax.shard_map(
+            body, mesh=self.mesh,
             in_specs=(_SH, _SH, _SH, _SH, _SH, _SH, P(), P()),
             out_specs=(_SH, _SH, _SH, _SH, _SH, _SH, _SH, _SH, P()),
-            check_rep=False))
+            check_vma=False))
         entry = (fn, exit_bounds, seg_cer, seg_fail, n_computes, gather_ops)
         self._shard_jit[b] = entry
         return entry
@@ -204,7 +202,7 @@ class _ShardLoopBase:
         parts = jnp.stack([l[5] for l in lanes])
         bufs = {si: self._buffers[si] for si in seg_cer}
         fbufs = {si: self._fail_buffers[si] for si in seg_fail}
-        with enable_x64():                           # leaf reduce is int64
+        with jax.enable_x64(True):                   # leaf reduce is int64
             (leaf_tile, terms, cnt, ovf, packed, frontiers, bufs2, fbufs2,
              total) = fn(tiles, rs, cursors, bufs, fbufs, parts, aux1, aux2)
         for si in seg_cer:
@@ -357,7 +355,8 @@ class ShardedTileScheduler(_ShardLoopBase, TileScheduler):
                     c = int(cnt_np[s])
                 if materialize and c:
                     embeddings.extend(
-                        eng._materialize(_lane_slice(leaf_tile, s)))
+                        eng._materialize(_lane_slice(leaf_tile, s),
+                                         limit - len(embeddings)))
                 lane_sum += c
             # psum total is the primary count; the per-lane walk replaces
             # it only when a shard tripped the exact host fallback

@@ -22,7 +22,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref as kref
@@ -72,8 +71,8 @@ def sharded_decode_attention(q, k, v, lengths, mesh, *, axis: str = "model"):
         return (numer / jnp.maximum(denom, 1e-30)[..., None]).astype(q.dtype)
 
     rest = tuple(a for a in mesh.axis_names if a != axis)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(None, axis, None, None), P(None, axis, None, None),
                   P()),
-        out_specs=P(), check_rep=False)(q, k, v, lengths)
+        out_specs=P(), check_vma=False)(q, k, v, lengths)

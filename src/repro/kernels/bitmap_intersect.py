@@ -5,17 +5,25 @@ The CEMR enumeration hot loop (Algorithm 3 line 5 / engine._compute_fn):
     R[t, :] = AND_j  table_j[idx[t, j], :]        (k gathered rows per tile row)
     pop[t]  = popcount(R[t, :])
 
-Layout: tables live in HBM as (S_j, W) uint32; the per-row gather is expressed
-through scalar-prefetched indices driving each input's BlockSpec index_map —
-the canonical Pallas TPU embedding-gather pattern. Grid = (T, W/WB): one
-frontier row per grid step, WB words staged through VMEM. On a real TPU the
-word-block WB should be sized so k·WB·4B ≈ a few KB per step to amortize HBM
-latency (the workload is memory-bound: arithmetic intensity ≈ k AND-ops per
-4·k bytes gathered — see EXPERIMENTS.md §Roofline[cemr-engine]).
+Layout: each (S_j, W) uint32 table is zero-padded to W_pad, the next multiple
+of 128 lanes, and viewed as (S_j, 1, W_pad). XLA lays that view out in
+(1, 128) tiles, so one table row is one contiguous, tile-aligned DMA. The
+tables stay in HBM and the row indices are scalar-prefetched into SMEM. Grid
+step i owns `ROWS_PER_STEP` frontier rows: it starts one row DMA per (row,
+table) into VMEM scratch, waits for all of them, ANDs the k rows and writes an
+(8, W_pad) block of R plus its (8, 1) popcount column. Zero padding words AND
+and popcount to nothing, so the result is cut back to (T, W) unchanged.
 
-Popcount is fused so the contained-vertex prune (Lemma 2) never re-reads R
-from HBM: the per-row count accumulates across word blocks in the (T, 1)
-output, initialized at the first word block.
+A BlockSpec gather of single (1, W) rows, the obvious Pallas form, does not
+lower for the chip: Mosaic needs the last two block dimensions divisible by
+(8, 128) or equal to the array's, and a one-row slice of an (8, 128)-tiled
+table is refused. Interpret mode accepts it, so only a compile for the chip
+(tests/test_tpu_compile.py) shows the difference.
+
+Indices follow jnp gather semantics: a negative index counts from the end,
+and whatever is still out of range clamps to [0, S-1]. The kernel is then
+bit-identical to `kernels/ref.py` for every index, including the clamped
+selections the engine leaves in dead rows.
 """
 from __future__ import annotations
 
@@ -27,31 +35,83 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["bitmap_intersect_pallas", "fused_expand_intersect_pallas",
-           "autotune_words_per_block", "FUSED_TILE_WIDTHS"]
+           "ROWS_PER_STEP", "LANES"]
+
+ROWS_PER_STEP = 8     # frontier rows per grid step: one 8-sublane output tile
+LANES = 128           # W is padded to a multiple of the lane width
 
 
-def _kernel(k: int, n_wb: int, idx_ref, *refs):
-    table_blocks = refs[:k]
-    r_ref, pop_ref = refs[k], refs[k + 1]
-    r = table_blocks[0][...]
+def _clamp_index(i, n: int):
+    """jnp gather semantics for one scalar index into n rows."""
+    i = jnp.where(i < 0, i + n, i)
+    return jnp.minimum(jnp.maximum(i, 0), n - 1)
+
+
+def _gather_and_kernel(resolve, n_rows: tuple, *refs):
+    """Shared body of both entry points. `resolve(prefetch_refs, t, j)`
+    returns the (unclamped) row of table j for frontier row t."""
+    k = len(n_rows)
+    n_pf = len(refs) - k - 4
+    pf, tables = refs[:n_pf], refs[n_pf:n_pf + k]
+    r_ref, pop_ref, buf, sem = refs[n_pf + k:]
+    base = pl.program_id(0) * ROWS_PER_STEP
+
+    def row_copy(t, j):
+        row = _clamp_index(resolve(pf, base + t, j), n_rows[j])
+        return pltpu.make_async_copy(tables[j].at[pl.ds(row, 1)],
+                                     buf.at[j, pl.ds(t, 1)], sem.at[0])
+
+    copies = [row_copy(t, j) for t in range(ROWS_PER_STEP) for j in range(k)]
+    for c in copies:
+        c.start()
+    for c in copies:
+        c.wait()
+    r = buf[0]
     for j in range(1, k):
-        r = r & table_blocks[j][...]
+        r = r & buf[j]
+    r = r.reshape(ROWS_PER_STEP, r.shape[-1])
     r_ref[...] = r
-    w = pl.program_id(1)
-
-    @pl.when(w == 0)
-    def _init():
-        pop_ref[...] = jnp.zeros_like(pop_ref)
-
-    # explicit accumulator dtype: keeps the popcount int32 even when the
-    # caller traces under x64 (the scheduler's leaf supersteps)
-    pop_ref[...] += jax.lax.population_count(r).astype(jnp.int32).sum(
+    pop_ref[...] = jax.lax.population_count(r).astype(jnp.int32).sum(
         axis=1, keepdims=True, dtype=jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("words_per_block", "interpret"))
+def _gather_and(tables: tuple, prefetch: tuple, t_rows: int, resolve,
+                interpret: bool):
+    """Run the kernel over `t_rows` frontier rows; every prefetch array
+    must already cover `_pad_rows(t_rows)` rows where `resolve` reads it."""
+    k = len(tables)
+    w = tables[0].shape[1]
+    assert all(tbl.shape[1] == w for tbl in tables)
+    w_pad = pl.cdiv(w, LANES) * LANES
+    t_pad = _pad_rows(t_rows)
+    rows3 = tuple(jnp.pad(tbl, ((0, 0), (0, w_pad - w)))[:, None, :]
+                  for tbl in tables)
+    gs = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch), grid=(t_pad // ROWS_PER_STEP,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.HBM)] * k,
+        out_specs=[pl.BlockSpec((ROWS_PER_STEP, w_pad), lambda i, *_: (i, 0)),
+                   pl.BlockSpec((ROWS_PER_STEP, 1), lambda i, *_: (i, 0))],
+        scratch_shapes=[pltpu.VMEM((k, ROWS_PER_STEP, 1, w_pad), jnp.uint32),
+                        pltpu.SemaphoreType.DMA((1,))])
+    kernel = functools.partial(_gather_and_kernel, resolve,
+                               tuple(tbl.shape[0] for tbl in tables))
+    # the kernel is all 32-bit; traced under the caller's x64 (the leaf
+    # supersteps) its scalar indices would become i64, which Mosaic refuses
+    with jax.enable_x64(False):
+        r, pop = pl.pallas_call(
+            kernel, grid_spec=gs,
+            out_shape=(jax.ShapeDtypeStruct((t_pad, w_pad), jnp.uint32),
+                       jax.ShapeDtypeStruct((t_pad, 1), jnp.int32)),
+            interpret=interpret)(*prefetch, *rows3)
+    return r[:t_rows, :w], pop[:t_rows]
+
+
+def _pad_rows(t_rows: int) -> int:
+    return pl.cdiv(t_rows, ROWS_PER_STEP) * ROWS_PER_STEP
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def bitmap_intersect_pallas(tables: tuple, idxs: jnp.ndarray, *,
-                            words_per_block: int = 256,
                             interpret: bool = True):
     """AND k gathered bitmap rows per frontier row.
 
@@ -59,76 +119,30 @@ def bitmap_intersect_pallas(tables: tuple, idxs: jnp.ndarray, *,
     idxs:   (T, k) int32 row indices into each table
     Returns (R (T, W) uint32, pop (T, 1) int32).
     """
-    k = len(tables)
-    t_rows = idxs.shape[0]
-    w = tables[0].shape[1]
-    assert all(tbl.shape[1] == w for tbl in tables)
-    assert idxs.shape[1] == k
-    wb = min(words_per_block, w)
-    # pad W to a multiple of wb (zero words AND to zero: harmless)
-    w_pad = ((w + wb - 1) // wb) * wb
-    if w_pad != w:
-        tables = tuple(jnp.pad(tbl, ((0, 0), (0, w_pad - tbl.shape[1])))
-                       for tbl in tables)
-    n_wb = w_pad // wb
+    t_rows, k = idxs.shape
+    assert k == len(tables)
+    flat = jnp.pad(idxs.astype(jnp.int32),
+                   ((0, _pad_rows(t_rows) - t_rows), (0, 0))).reshape(-1)
 
-    grid = (t_rows, n_wb)
-    in_specs = [
-        pl.BlockSpec((1, wb),
-                     functools.partial(lambda j, t, wi, idx_ref: (idx_ref[t, j], wi), j))
-        for j in range(k)
-    ]
-    out_specs = [
-        pl.BlockSpec((1, wb), lambda t, wi, idx_ref: (t, wi)),
-        pl.BlockSpec((1, 1), lambda t, wi, idx_ref: (t, 0)),
-    ]
-    gs = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
-        out_specs=out_specs)
-    r, pop = pl.pallas_call(
-        functools.partial(_kernel, k, n_wb), grid_spec=gs,
-        out_shape=(jax.ShapeDtypeStruct((t_rows, w_pad), jnp.uint32),
-                   jax.ShapeDtypeStruct((t_rows, 1), jnp.int32)),
-        interpret=interpret)(idxs, *tables)
-    return r[:, :w], pop
+    def resolve(pf, t, j):
+        return pf[0][t * k + j]
+
+    return _gather_and(tuple(tables), (flat,), t_rows, resolve, interpret)
 
 
-def _fused_kernel(k: int, rows_ref, bitpos_ref, idx_ref, *refs):
-    # identical compute body to _kernel — the fusion lives entirely in the
-    # in_specs index_maps (double indirection through rows/bitpos/idx)
-    table_blocks = refs[:k]
-    r_ref, pop_ref = refs[k], refs[k + 1]
-    r = table_blocks[0][...]
-    for j in range(1, k):
-        r = r & table_blocks[j][...]
-    r_ref[...] = r
-    w = pl.program_id(1)
-
-    @pl.when(w == 0)
-    def _init():
-        pop_ref[...] = jnp.zeros_like(pop_ref)
-
-    pop_ref[...] += jax.lax.population_count(r).astype(jnp.int32).sum(
-        axis=1, keepdims=True, dtype=jnp.int32)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("slots", "words_per_block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("slots", "interpret"))
 def fused_expand_intersect_pallas(tables: tuple, idx: jnp.ndarray,
                                   rows: jnp.ndarray, bitpos: jnp.ndarray, *,
-                                  slots: tuple,
-                                  words_per_block: int = 32,
-                                  interpret: bool = True):
+                                  slots: tuple, interpret: bool = True):
     """Fused frontier expansion + k-way bitmap AND + popcount.
 
     Consumes the bit selection from `core.bitops.expand_select` directly:
     instead of first materializing the child tile's gathered index columns
-    (``concat(idx[rows], bitpos)``) and then gathering table rows through
-    them, each table's BlockSpec index_map double-indirects through the
-    scalar-prefetched (rows, bitpos, idx) triple — slot ``s < K0`` reads
-    parent column ``idx[rows[t], s]``, slot ``s == K0`` reads the freshly
-    selected bit position ``bitpos[t]``. The AND and per-row popcount then
-    run per word-block exactly like `bitmap_intersect_pallas`.
+    (``concat(idx[rows], bitpos)``), each row DMA resolves its table row
+    through the scalar-prefetched (rows, bitpos, idx) triple — slot
+    ``s < K0`` reads parent column ``idx[rows[t], s]``, slot ``s == K0``
+    reads the freshly selected bit position ``bitpos[t]``. The AND and
+    per-row popcount are the body of `bitmap_intersect_pallas`.
 
     tables: k × (S_j, W) uint32 adjacency bitmaps
     idx:    (Tin, K0) int32 parent tile index columns (K0 may be 0)
@@ -140,101 +154,22 @@ def fused_expand_intersect_pallas(tables: tuple, idx: jnp.ndarray,
     so CER cache entries built from it remain sound (clamped selections
     are valid keys); the engine's finish_compute masks downstream.
     """
-    k = len(tables)
-    assert len(slots) == k
+    assert len(slots) == len(tables)
     t_rows = rows.shape[0]
-    w = tables[0].shape[1]
-    assert all(tbl.shape[1] == w for tbl in tables)
-    k0 = idx.shape[1]
-    if k0 == 0:                     # keep the prefetch ref 2-D and non-empty;
-        idx = jnp.zeros((idx.shape[0], 1), jnp.int32)  # never dereferenced
-    wb = min(words_per_block, w)
-    w_pad = ((w + wb - 1) // wb) * wb
-    if w_pad != w:                  # zero pad words AND/popcount to nothing
-        tables = tuple(jnp.pad(tbl, ((0, 0), (0, w_pad - tbl.shape[1])))
-                       for tbl in tables)
+    t_in, k0 = idx.shape
+    pad = _pad_rows(t_rows) - t_rows
+    rows_p = jnp.pad(rows.astype(jnp.int32), (0, pad))
+    bitpos_p = jnp.pad(bitpos.astype(jnp.int32), (0, pad))
+    # K0 == 0: every slot is the bitpos slot and idx is never read, but the
+    # prefetch operand must be non-empty
+    flat_idx = (idx.astype(jnp.int32).reshape(-1) if k0
+                else jnp.zeros((1,), jnp.int32))
 
-    grid = (t_rows, w_pad // wb)
+    def resolve(pf, t, j):
+        rows_ref, bitpos_ref, idx_ref = pf
+        if slots[j] == k0:
+            return bitpos_ref[t]
+        return idx_ref[_clamp_index(rows_ref[t], t_in) * k0 + slots[j]]
 
-    def _map_parent(s, t, wi, rows_ref, bitpos_ref, idx_ref):
-        return idx_ref[rows_ref[t], s], wi
-
-    def _map_bitpos(t, wi, rows_ref, bitpos_ref, idx_ref):
-        return bitpos_ref[t], wi
-
-    in_specs = [
-        pl.BlockSpec((1, wb), (_map_bitpos if s == k0
-                               else functools.partial(_map_parent, s)))
-        for s in slots
-    ]
-    out_specs = [
-        pl.BlockSpec((1, wb),
-                     lambda t, wi, rows_ref, bitpos_ref, idx_ref: (t, wi)),
-        pl.BlockSpec((1, 1),
-                     lambda t, wi, rows_ref, bitpos_ref, idx_ref: (t, 0)),
-    ]
-    gs = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3, grid=grid, in_specs=in_specs,
-        out_specs=out_specs)
-    r, pop = pl.pallas_call(
-        functools.partial(_fused_kernel, k), grid_spec=gs,
-        out_shape=(jax.ShapeDtypeStruct((t_rows, w_pad), jnp.uint32),
-                   jax.ShapeDtypeStruct((t_rows, 1), jnp.int32)),
-        interpret=interpret)(rows, bitpos, idx, *tables)
-    return r[:, :w], pop
-
-
-# ------------------------------------------------------------------ autotune
-# The word-block width only changes how the fused kernel tiles HBM reads —
-# every width is bit-identical by construction (zero padding ANDs/popcounts
-# to nothing; tests/test_kernels.py sweeps the widths against the oracle), so
-# autotuning can never change *what* is computed, only how fast.
-FUSED_TILE_WIDTHS = (8, 16, 32)
-
-_AUTOTUNE_CACHE: dict = {}
-
-
-def autotune_words_per_block(k: int, w: int, *, interpret: bool = True,
-                             widths: tuple = FUSED_TILE_WIDTHS) -> int:
-    """Pick the fused kernel's word-block width for a (k tables, W words)
-    shape by timing a synthetic sweep on the current backend, cached per
-    (backend, k, W, interpret).
-
-    The winner's wall time is sanity-checked against the roofline HBM
-    lower bound (`launch.roofline.HW`): a measurement faster than
-    ``k·T·W·4B / hbm_bw`` is physically impossible on TPU and means the
-    timer glitched, in which case the largest (most conservative) width
-    is returned instead of trusting the sweep.
-    """
-    import time
-
-    import jax as _jax
-
-    key = (_jax.default_backend(), k, w, bool(interpret))
-    if key in _AUTOTUNE_CACHE:
-        return _AUTOTUNE_CACHE[key]
-    t_rows, s_rows = 64, 128
-    tabs = tuple(jnp.full((s_rows, w), jnp.uint32(0x5A5A5A5A + j))
-                 for j in range(k))
-    idx = (jnp.arange(t_rows, dtype=jnp.int32) % s_rows)[:, None]
-    rows = jnp.arange(t_rows, dtype=jnp.int32) % t_rows
-    bitpos = (jnp.arange(t_rows, dtype=jnp.int32) * 7) % s_rows
-    slots = (1,) + (0,) * (k - 1)          # exercise both indirections
-    best, best_t = None, None
-    for wb in widths:
-        fn = lambda: fused_expand_intersect_pallas(    # noqa: E731
-            tabs, idx, rows, bitpos, slots=slots, words_per_block=wb,
-            interpret=interpret)
-        _jax.block_until_ready(fn())       # compile outside the timing
-        t0 = time.perf_counter()
-        for _ in range(3):
-            _jax.block_until_ready(fn())
-        dt = (time.perf_counter() - t0) / 3
-        if best_t is None or dt < best_t:
-            best, best_t = wb, dt
-    from repro.launch.roofline import HW
-    floor = k * t_rows * w * 4 / HW["hbm_bw"]
-    if not interpret and best_t is not None and best_t < floor:
-        best = max(widths)                 # timer glitch: don't trust sweep
-    _AUTOTUNE_CACHE[key] = best
-    return best
+    return _gather_and(tuple(tables), (rows_p, bitpos_p, flat_idx), t_rows,
+                       resolve, interpret)

@@ -13,14 +13,13 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
-from .bitmap_intersect import (autotune_words_per_block,
-                               bitmap_intersect_pallas,
+from .bitmap_intersect import (bitmap_intersect_pallas,
                                fused_expand_intersect_pallas)
 from .flash_decode import flash_decode_pallas
 
 __all__ = ["bitmap_intersect", "flash_decode", "fused_expand_intersect",
            "make_intersect_fn", "make_fused_expand_intersect_fn",
-           "autotune_words_per_block", "decode_attention",
+           "decode_attention",
            "default_interpret", "on_tpu"]
 
 
@@ -36,15 +35,12 @@ def default_interpret() -> bool:
 
 
 def bitmap_intersect(tables, idxs, *, use_pallas: bool = False,
-                     interpret: bool | None = None,
-                     words_per_block: int = 256):
+                     interpret: bool | None = None):
     tables = tuple(tables)
     if use_pallas:
         if interpret is None:
             interpret = default_interpret()
-        return bitmap_intersect_pallas(tables, idxs,
-                                       words_per_block=words_per_block,
-                                       interpret=interpret)
+        return bitmap_intersect_pallas(tables, idxs, interpret=interpret)
     return ref.bitmap_intersect_ref(tables, idxs)
 
 
@@ -60,10 +56,9 @@ def flash_decode(q, k, v, lengths=None, *, use_pallas: bool = False,
 
 def fused_expand_intersect(tables, idx, rows, bitpos, *, slots,
                            use_pallas: bool = True,
-                           interpret: bool | None = None,
-                           words_per_block: int | None = None):
+                           interpret: bool | None = None):
     """Fused frontier expansion + intersection + popcount (or its two-step
-    jnp oracle). `words_per_block=None` autotunes per backend/shape."""
+    jnp oracle)."""
     tables = tuple(tables)
     slots = tuple(slots)
     if not use_pallas:
@@ -71,18 +66,12 @@ def fused_expand_intersect(tables, idx, rows, bitpos, *, slots,
                                               slots=slots)
     if interpret is None:
         interpret = default_interpret()
-    if words_per_block is None:
-        words_per_block = autotune_words_per_block(
-            len(tables), tables[0].shape[1], interpret=interpret)
     return fused_expand_intersect_pallas(tables, idx, rows, bitpos,
-                                         slots=slots,
-                                         words_per_block=words_per_block,
-                                         interpret=interpret)
+                                         slots=slots, interpret=interpret)
 
 
 def make_fused_expand_intersect_fn(*, use_pallas: bool = True,
-                                   interpret: bool | None = None,
-                                   words_per_block: int | None = None):
+                                   interpret: bool | None = None):
     """Adapter for core.engine._make_expand_fused: takes the backward-pair
     tables, parent index columns, the (rows, bitpos) bit selection and the
     static slot map; returns ``(R, pop)`` with pop flattened to (T,)."""
@@ -91,8 +80,7 @@ def make_fused_expand_intersect_fn(*, use_pallas: bool = True,
         r, pop = fused_expand_intersect(tables, idx, rows, bitpos,
                                         slots=tuple(slots),
                                         use_pallas=use_pallas,
-                                        interpret=interpret,
-                                        words_per_block=words_per_block)
+                                        interpret=interpret)
         return r, pop.reshape(-1)
 
     return fn

@@ -35,6 +35,10 @@ from repro.models.api import build_bundle   # noqa: E402
 
 __all__ = ["dryrun_cell", "dryrun_engine_cell"]
 
+# the production meshes are v5e pods: their roofline uses v5e peaks, not
+# those of the host the dry-run compiles on
+TARGET_KIND = "TPU v5 lite"
+
 
 def _batch_of(specs: dict, shape_id: str) -> int:
     for k in ("tokens", "token", "ids"):
@@ -164,7 +168,8 @@ def dryrun_cell(arch: str, shape_id: str, mesh, *, verbose: bool = True,
     # XLA:CPU byte count is kept as an aux field but is not TPU-meaningful.
     hbm_floor = hbm_floor_bytes(bundle, shape_id, mesh)
     terms = roofline_terms({"flops": flops, "bytes accessed": hbm_floor}, "",
-                           chips, model_flops=bundle.model_flops(shape_id))
+                           chips, TARGET_KIND,
+                           model_flops=bundle.model_flops(shape_id))
     terms.coll_breakdown = coll
     terms.coll_bytes = float(sum(coll.values()))
     terms.collective_s = terms.coll_bytes / 50e9
@@ -232,7 +237,7 @@ def dryrun_engine_cell(mesh, *, frontier_rows: int = 65_536,
     lowered = fn.lower(idx_spec, *t_specs)
     compiled = lowered.compile()
     cost = compiled.cost_analysis()
-    terms = roofline_terms(cost, compiled.as_text(), mesh.size,
+    terms = roofline_terms(cost, compiled.as_text(), mesh.size, TARGET_KIND,
                            model_flops=float(frontier_rows * k_bwd * words))
     res = {"arch": "cemr-engine", "shape": f"T{frontier_rows}_S{space}",
            "mesh": dict(mesh.shape), "chips": mesh.size, "kind": "match",

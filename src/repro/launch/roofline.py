@@ -1,8 +1,12 @@
 """Roofline term extraction from compiled dry-run artifacts.
 
-  compute    = HLO_FLOPs / (chips × 197e12)          [bf16 TPU v5e]
-  memory     = HLO_bytes / (chips × 819e9)
-  collective = collective_bytes / (chips × 50e9)     [per ICI link]
+  compute    = HLO_FLOPs / (chips × peak bf16 FLOP/s)
+  memory     = HLO_bytes / (chips × peak HBM bytes/s)
+  collective = collective_bytes / (per-link ICI bytes/s)
+
+The peaks are looked up by device kind (`jax.Device.device_kind`) in
+`PEAKS`; a kind the table does not know raises instead of borrowing
+another chip's numbers.
 
 cost_analysis() provides FLOPs/bytes; collective bytes are parsed from the
 compiled (post-SPMD) HLO text by summing operand sizes of all-gather /
@@ -13,11 +17,25 @@ from __future__ import annotations
 import dataclasses
 import re
 
-__all__ = ["HW", "RooflineTerms", "collective_bytes", "roofline_terms",
-           "parse_memory_analysis"]
+__all__ = ["PEAKS", "peak_rates", "RooflineTerms", "collective_bytes",
+           "roofline_terms", "parse_memory_analysis"]
 
-# TPU v5e hardware constants
-HW = {"flops_bf16": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9}
+# Peak rates per device kind. TPU v5e ("TPU v5 lite"), from Google Cloud's
+# "TPU v5e" documentation: 197 TFLOP/s bf16, 819 GB/s HBM, and 1,600 Gbit/s
+# of chip-to-chip interconnect, i.e. 50 GB/s on each of its four ICI links.
+PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peak_rates(device_kind: str) -> dict:
+    """The peak rates of `device_kind`; raises KeyError for a kind without
+    a sourced entry in `PEAKS`."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak rates for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -92,19 +110,21 @@ class RooflineTerms:
         }
 
 
-def roofline_terms(cost: dict, hlo_text: str, chips: int,
+def roofline_terms(cost: dict, hlo_text: str, chips: int, device_kind: str,
                    model_flops: float = 0.0) -> RooflineTerms:
     """cost: compiled.cost_analysis() dict. Post-SPMD cost analysis reports
-    *per-device* flops; scale to the full step then divide by fleet rate."""
+    *per-device* flops; scale to the full step then divide by fleet rate
+    (the peaks of `device_kind`, see `peak_rates`)."""
+    hw = peak_rates(device_kind)
     flops = float(cost.get("flops", 0.0)) * chips
     hbm = float(cost.get("bytes accessed", 0.0)) * chips
     coll = collective_bytes(hlo_text)
     coll_total = float(sum(coll.values()))
     return RooflineTerms(
         flops=flops, hbm_bytes=hbm, coll_bytes=coll_total, chips=chips,
-        compute_s=flops / (chips * HW["flops_bf16"]),
-        memory_s=hbm / (chips * HW["hbm_bw"]),
-        collective_s=coll_total / HW["ici_bw"],
+        compute_s=flops / (chips * hw["flops_bf16"]),
+        memory_s=hbm / (chips * hw["hbm_bw"]),
+        collective_s=coll_total / hw["ici_bw"],
         coll_breakdown=coll, model_flops=model_flops)
 
 

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.distributed import policy
 from repro.distributed.sharding import sharding_ctx
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models.api import build_bundle
 
@@ -122,6 +123,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0,
                     help="arrival-schedule seed for --serve-loop")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.arch == "match":
         if args.serve_loop:

@@ -108,6 +108,9 @@ def execute_chunk(matcher: Matcher, chunk: list, *, batch: str = "auto",
                     [chunk[k].query for k in ks], limit=limit,
                     budget=max_steps, batch="auto")
             except Exception:    # noqa: BLE001 — isolate per item below
+                logger.warning("batched execution of %d items failed; "
+                               "retrying them one by one", len(ks),
+                               exc_info=True)
                 continue
             per = (time.perf_counter() - t0) / len(ks)
             for k, out in zip(ks, outs):
@@ -133,6 +136,7 @@ def execute_chunk(matcher: Matcher, chunk: list, *, batch: str = "auto",
                                 budget=it.max_steps)
             results.append((it, out, time.perf_counter() - t0))
         except Exception:    # noqa: BLE001 — executor died mid-item
+            logger.warning("item execution failed", exc_info=True)
             results.append((it, None, 0.0))
     return results
 

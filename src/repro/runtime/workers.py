@@ -32,6 +32,13 @@ inline:
     `hang_s` (the worker sleeps before executing — indistinguishable from
     a wedge, which is the point: the watchdog must recover it).
 
+**One process per chip.** On a TPU host a chip belongs to one process, so
+worker i is pinned to chip i before it initialises JAX
+(`worker_chip_env`), and the pool refuses to start when there are more
+workers than chips or when the parent has already initialised the TPU
+backend (it then holds the chips its workers would need). Off the TPU the
+workers share the host as before.
+
 Workers rebuild the `Dataset` from the pickled data `Graph` at startup and
 keep per-`(tenant, engine)` Matchers, so a bucket retried under a degraded
 engine (`engine="ref"` after repeated vector faults — the service's
@@ -50,10 +57,12 @@ import multiprocessing as mp
 import os
 import pickle
 import struct
+import sys
 import time
 from multiprocessing.connection import wait as _conn_wait
 
-__all__ = ["WorkerPool", "BucketResult", "WorkerOutcome", "as_triples"]
+__all__ = ["WorkerPool", "BucketResult", "WorkerOutcome", "as_triples",
+           "ChipPlacementError", "worker_chip_env", "host_chips"]
 
 _LEN = struct.Struct("!Q")
 
@@ -79,6 +88,63 @@ def _recv(conn):
         raise ValueError(f"torn frame: header says {n}, "
                          f"got {len(data) - _LEN.size}")
     return pickle.loads(data[_LEN.size:])
+
+
+# ------------------------------------------------------------ chip placement
+class ChipPlacementError(RuntimeError):
+    """The pool cannot give every worker a TPU chip of its own."""
+
+
+_TPU_BASE_PORT = 8476       # libtpu's default; worker i listens on base + i
+
+
+def worker_chip_env(platform: str, n_chips: int, n_workers: int,
+                    parent_holds_chip: bool) -> list[dict[str, str]]:
+    """Environment for each worker, as a pure function of the host.
+
+    On a TPU host worker i sees chip i alone (libtpu's one-chip-per-process
+    variables). It raises `ChipPlacementError` when the parent already
+    holds the TPU backend or when there are more workers than chips: a
+    worker without a chip of its own would fail or hang at its first
+    device call. Any other platform needs no placement."""
+    if platform != "tpu":
+        return [{} for _ in range(n_workers)]
+    if parent_holds_chip:
+        raise ChipPlacementError(
+            "this process has already initialised the TPU backend and so "
+            "holds every chip; pool workers could not reach theirs. Start "
+            "the pool before any JAX computation, or run inline "
+            "(workers=0)")
+    if n_workers > n_chips:
+        raise ChipPlacementError(
+            f"{n_workers} workers but {n_chips} TPU chips on this host: "
+            f"each worker needs a chip of its own")
+    return [{"TPU_VISIBLE_CHIPS": str(i),
+             "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+             "TPU_PROCESS_BOUNDS": "1,1,1",
+             "TPU_PROCESS_PORT": str(_TPU_BASE_PORT + i)}
+            for i in range(n_workers)]
+
+
+def host_chips() -> tuple[str, int, bool]:
+    """(platform, TPU chips on the PCI bus, whether this process holds
+    the TPU backend), found without initialising JAX. `JAX_PLATFORMS`,
+    when set, names the platform; otherwise a host with TPU chips is a
+    TPU host."""
+    from jax._src import hardware_utils
+
+    n_chips, _ = hardware_utils.num_available_tpu_chips_and_device_id()
+    forced = os.environ.get("JAX_PLATFORMS", "")
+    if forced:
+        platform = "tpu" if "tpu" in forced.split(",") else forced
+    else:
+        platform = "tpu" if n_chips else "cpu"
+    holds = False
+    if "jax" in sys.modules:
+        from jax._src import xla_bridge
+        holds = (xla_bridge.backends_are_initialized()
+                 and "tpu" in xla_bridge.backends())
+    return platform, n_chips, holds
 
 
 # ----------------------------------------------------------------- outcomes
@@ -140,8 +206,9 @@ class _Item:
     max_steps: int | None
 
 
-def _worker_main(conn, graph, options) -> None:
-    """Child-process entry: build the Dataset once, then serve frames.
+def _worker_main(conn, graph, options, chip_env) -> None:
+    """Child-process entry: take this worker's chip (`chip_env`, before
+    anything initialises JAX), build the Dataset once, then serve frames.
 
     Protocol (all frames are length-prefixed pickles):
       parent -> {"op": "ping"}                      -> {"op": "pong"}
@@ -155,6 +222,7 @@ def _worker_main(conn, graph, options) -> None:
     `execute_chunk` (that item's count is None, siblings complete); a
     crash that kills this process is the parent watchdog's problem.
     """
+    os.environ.update(chip_env)
     # heavy imports belong to the child: the parent never pays them here
     from repro.api import Dataset, Matcher
 
@@ -249,17 +317,25 @@ class WorkerPool:
         self._next_ticket = 0
         self._closed = False
         self.size = n_workers
+        # raises before anything is spawned when the chips cannot be shared
+        # out (a worker that boot-fails would only surface as a degraded
+        # engine much later)
+        platform, n_chips, holds = host_chips()
+        self._chip_env = worker_chip_env(platform, n_chips, n_workers, holds)
         self.stats = {"spawned": 0, "respawned": 0, "deaths": 0,
                       "watchdog_kills": 0, "chaos_kills": 0,
                       "dispatched": 0, "completed": 0, "pings": 0,
                       "worker_cache_hits": 0}
-        self._workers = [self._spawn() for _ in range(n_workers)]
+        self._workers = [self._spawn(i) for i in range(n_workers)]
 
     # --------------------------------------------------------------- lifecycle
-    def _spawn(self) -> _Worker:
+    def _spawn(self, slot: int) -> _Worker:
+        """Start the worker for `slot`; a respawn reuses the slot, and so
+        the chip, of the worker it replaces."""
         parent, child = self._ctx.Pipe(duplex=True)
         proc = self._ctx.Process(
-            target=_worker_main, args=(child, self._graph, self._options),
+            target=_worker_main,
+            args=(child, self._graph, self._options, self._chip_env[slot]),
             daemon=True, name=f"match-worker-{self.stats['spawned']}")
         proc.start()
         child.close()                 # the child's end lives in the child
@@ -299,7 +375,8 @@ class WorkerPool:
                     f"broken (not a query fault)")
         self._kill(w)
         self.stats["respawned"] += 1
-        self._workers[self._workers.index(w)] = self._spawn()
+        slot = self._workers.index(w)
+        self._workers[slot] = self._spawn(slot)
 
     def close(self) -> None:
         """Shut the pool down: polite stop for idle workers, SIGKILL for
@@ -451,7 +528,8 @@ class WorkerPool:
                     worker_died=True, hung=True))
                 self.stats["deaths"] += 1
                 self.stats["respawned"] += 1
-                self._workers[self._workers.index(w)] = self._spawn()
+                slot = self._workers.index(w)
+                self._workers[slot] = self._spawn(slot)
             elif w.state == _STARTING and now > w.boot_deadline:
                 self._respawn(w, results)
         return results

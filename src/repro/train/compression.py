@@ -12,7 +12,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["quantize_int8", "dequantize_int8", "compressed_psum",
@@ -57,7 +56,7 @@ def compressed_allreduce_tree(grads, mesh, axes=("data",)):
     def one(g):
         def f(gl):
             return compressed_psum(gl, axis)
-        return shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-                         check_rep=False)(g)
+        return jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+                             check_vma=False)(g)
 
     return jax.tree.map(one, grads)

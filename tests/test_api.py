@@ -1,6 +1,7 @@
 """Tests for the repro.api session layer (Dataset / MatchOptions / Matcher):
 engine agreement through the facade, plan-cache behavior, options validation,
 streaming, queue integration, and deprecation shims."""
+import numpy as np
 import pytest
 from strategies import fig1_pair
 
@@ -192,6 +193,46 @@ def test_stream_yields_valid_embeddings_and_honors_limit():
     # laziness: creating the iterator does no work until first item
     it = m.stream(query)
     assert hasattr(it, "__next__")
+
+
+@pytest.mark.parametrize("limit", [1, 3, 40])
+def test_vector_materialize_stops_at_limit(limit):
+    """A leaf tile's rows expand to the product of their bitmap sets, far
+    more than `limit` on a large graph: decoding stops at the limit, and
+    what it keeps is the prefix of the uncapped enumeration."""
+    g = synthetic_labeled_graph(300, 6.0, 2, seed=3)
+    q = random_walk_query(g, 4, seed=2)
+    m = Matcher(Dataset.from_graph(g))
+    full = m.count(q, engine="vector", materialize=True, limit=10**9)
+    assert full.count > 40 and len(full.embeddings) == full.count
+    out = m.count(q, engine="vector", materialize=True, limit=limit)
+    assert out.count == limit
+    assert out.embeddings == full.embeddings[:limit]
+    assert all(_is_embedding(q, g, e) for e in out.embeddings)
+
+
+def test_vector_materialize_reads_strided_host_copies(monkeypatch):
+    """A leaf tile copied from the TPU can come back with rows that are not
+    contiguous in host memory: decoding must not depend on the host
+    copy's memory order."""
+    from repro.core.engine import VectorEngine
+    g = synthetic_labeled_graph(300, 6.0, 2, seed=3)
+    q = random_walk_query(g, 4, seed=2)
+    want = Matcher(Dataset.from_graph(g)).count(
+        q, engine="vector", materialize=True, limit=10**9).embeddings
+    decode = VectorEngine._materialize
+
+    def strided(self, tile, cap):
+        bm = {u: np.asfortranarray(np.asarray(v))
+              for u, v in tile["bm"].items()}
+        assert any(b.ndim == 2 and b.shape[1] > 1 and b.shape[0] > 1
+                   for b in bm.values())
+        return decode(self, dict(tile, bm=bm), cap)
+
+    monkeypatch.setattr(VectorEngine, "_materialize", strided)
+    got = Matcher(Dataset.from_graph(g)).count(
+        q, engine="vector", materialize=True, limit=10**9).embeddings
+    assert got == want and len(got) > 40
 
 
 def test_match_many_shares_cache():

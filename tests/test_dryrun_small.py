@@ -62,8 +62,17 @@ def test_collective_bytes_parser():
 
 def test_roofline_terms_math():
     t = roofline_terms({"flops": 1.97e14, "bytes accessed": 8.19e11}, "",
-                       chips=4, model_flops=1.97e14 * 2)
+                       chips=4, device_kind="TPU v5 lite",
+                       model_flops=1.97e14 * 2)
     assert abs(t.compute_s - 1.0) < 1e-9       # 1.97e14 per dev / peak
     assert abs(t.memory_s - 1.0) < 1e-9
     assert t.dominant in ("compute", "memory")
     assert abs(t.useful_fraction - 0.5) < 1e-9
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v4", ""])
+def test_roofline_terms_refuse_unknown_device_kind(kind):
+    """A kind without a sourced entry in PEAKS raises; it never borrows
+    the v5e numbers."""
+    with pytest.raises(KeyError, match="no peak rates"):
+        roofline_terms({"flops": 1.0}, "", chips=1, device_kind=kind)

@@ -22,29 +22,55 @@ def test_bitmap_intersect_sweep(k, t_rows, w):
         [rng.integers(0, tbl.shape[0], t_rows) for tbl in tables], 1
     ).astype(np.int32))
     r_ref, pop_ref = ref.bitmap_intersect_ref(tables, idxs)
-    r_pal, pop_pal = bitmap_intersect_pallas(tables, idxs, words_per_block=4)
+    r_pal, pop_pal = bitmap_intersect_pallas(tables, idxs)
     np.testing.assert_array_equal(np.asarray(r_pal), np.asarray(r_ref))
     np.testing.assert_array_equal(np.asarray(pop_pal), np.asarray(pop_ref))
 
 
-@pytest.mark.parametrize("wpb", [1, 2, 256])
-def test_bitmap_intersect_word_blocking(wpb):
-    rng = np.random.default_rng(0)
-    tables = tuple(jnp.asarray(rng.integers(0, 2**32, size=(16, 9),
+@pytest.mark.parametrize("w", [1, 127, 128, 129, 256])
+def test_bitmap_intersect_word_blocking(w):
+    """W on both sides of the 128-lane padding: the zero pad words must
+    AND and popcount to nothing."""
+    rng = np.random.default_rng(w)
+    tables = tuple(jnp.asarray(rng.integers(0, 2**32, size=(16, w),
                                             dtype=np.uint32)) for _ in range(2))
     idxs = jnp.asarray(rng.integers(0, 16, size=(12, 2)).astype(np.int32))
     r_ref, pop_ref = ref.bitmap_intersect_ref(tables, idxs)
-    r, pop = bitmap_intersect_pallas(tables, idxs, words_per_block=wpb)
+    r, pop = bitmap_intersect_pallas(tables, idxs)
+    np.testing.assert_array_equal(np.asarray(r), np.asarray(r_ref))
+    np.testing.assert_array_equal(np.asarray(pop), np.asarray(pop_ref))
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_intersect_out_of_range_indices_clamp_like_jnp(fused):
+    """Negative and too-large indices resolve as a jnp gather does
+    (negative counts from the end, then clip to [0, S-1]); on the chip an
+    unclamped row DMA would read outside the table."""
+    rng = np.random.default_rng(4)
+    tables = tuple(jnp.asarray(rng.integers(0, 2**32, size=(s, 3),
+                                            dtype=np.uint32)) for s in (5, 9))
+    bad = np.array([-1, -5, -9, -40, 0, 4, 5, 8, 9, 1000], np.int32)
+    if fused:
+        idx = jnp.asarray(np.stack([bad[::-1], bad], 1))
+        rows = jnp.asarray(np.array([-1, 0, 3, 9, 10, 50, -20, 2, 7, 1],
+                                    np.int32))
+        args = (tables, idx, rows, jnp.asarray(bad))
+        r_ref, pop_ref = ref.fused_expand_intersect_ref(*args, slots=(2, 0))
+        r, pop = fused_expand_intersect_pallas(*args, slots=(2, 0))
+    else:
+        idxs = jnp.asarray(np.stack([bad, bad[::-1]], 1))
+        r_ref, pop_ref = ref.bitmap_intersect_ref(tables, idxs)
+        r, pop = bitmap_intersect_pallas(tables, idxs)
     np.testing.assert_array_equal(np.asarray(r), np.asarray(r_ref))
     np.testing.assert_array_equal(np.asarray(pop), np.asarray(pop_ref))
 
 
 # ----------------------------------------------- fused expand + intersect
-def _fused_case(k, t_rows, t_in, w, seed, *, fill=None):
+def _fused_case(k, t_rows, t_in, w, seed, *, fill=None, k0=None):
     """Synthetic (tables, idx, rows, bitpos, slots) for the fused kernel:
-    k0 = k-1 parent columns plus the bitpos slot, mixed slot map."""
+    k0 parent columns (default k-1) plus the bitpos slot, mixed slot map."""
     rng = np.random.default_rng(seed)
-    k0 = max(k - 1, 1)
+    k0 = max(k - 1, 1) if k0 is None else k0
     s_max = 33                                    # rows per table
     if fill is None:
         tables = tuple(
@@ -63,19 +89,20 @@ def _fused_case(k, t_rows, t_in, w, seed, *, fill=None):
     return tables, idx, rows, bitpos, slots
 
 
-@pytest.mark.parametrize("wpb", [8, 16, 32])
+@pytest.mark.parametrize("k0", [3, 8, 23])
 @pytest.mark.parametrize("k", [1, 2, 3])
 @pytest.mark.parametrize("t_rows,w", [(1, 1), (16, 5), (33, 40)])
-def test_fused_expand_intersect_width_sweep(k, t_rows, w, wpb):
-    """Fused expand+intersect+popcount vs the two-step oracle across the
-    autotunable tile widths {8, 16, 32} and word counts — autotune can
-    never pick a width that diverges."""
+def test_fused_expand_intersect_width_sweep(k, t_rows, w, k0):
+    """Fused expand+intersect+popcount vs the two-step oracle across word
+    counts, row counts off the 8-row grid step, and parent tiles of
+    several widths (the flattened idx stride the kernel resolves with)."""
     tables, idx, rows, bitpos, slots = _fused_case(k, t_rows, 24, w,
-                                                   seed=k * 77 + t_rows + w)
+                                                   seed=k * 77 + t_rows + w,
+                                                   k0=k0)
     r_ref, pop_ref = ref.fused_expand_intersect_ref(tables, idx, rows,
                                                     bitpos, slots=slots)
     r_pal, pop_pal = fused_expand_intersect_pallas(
-        tables, idx, rows, bitpos, slots=slots, words_per_block=wpb)
+        tables, idx, rows, bitpos, slots=slots)
     np.testing.assert_array_equal(np.asarray(r_pal), np.asarray(r_ref))
     np.testing.assert_array_equal(np.asarray(pop_pal), np.asarray(pop_ref))
 
@@ -87,7 +114,7 @@ def test_fused_expand_intersect_bitmap_edges(fill):
     tables, idx, rows, bitpos, slots = _fused_case(2, 16, 8, 7, seed=5,
                                                    fill=fill)
     r, pop = fused_expand_intersect_pallas(tables, idx, rows, bitpos,
-                                           slots=slots, words_per_block=8)
+                                           slots=slots)
     want = 0 if fill == 0 else 32 * 7
     np.testing.assert_array_equal(np.asarray(pop).ravel(),
                                   np.full(16, want))
@@ -108,23 +135,21 @@ def test_fused_expand_intersect_no_parent_columns():
     r_ref, pop_ref = ref.fused_expand_intersect_ref(tables, idx, rows,
                                                     bitpos, slots=(0,))
     r, pop = fused_expand_intersect_pallas(tables, idx, rows, bitpos,
-                                           slots=(0,), words_per_block=16)
+                                           slots=(0,))
     np.testing.assert_array_equal(np.asarray(r), np.asarray(r_ref))
     np.testing.assert_array_equal(np.asarray(pop), np.asarray(pop_ref))
 
 
 @pytest.mark.skipif(not ops.on_tpu(), reason="compiled Pallas needs a TPU")
-@pytest.mark.parametrize("wpb", [8, 16, 32])
-def test_fused_expand_intersect_compiled_matches_interpret(wpb):
+@pytest.mark.parametrize("w", [1, 152, 660])
+def test_fused_expand_intersect_compiled_matches_interpret(w):
     """On TPU the compiled kernel must agree with interpret mode (which the
     CPU sweeps above pin to the oracle)."""
-    tables, idx, rows, bitpos, slots = _fused_case(2, 32, 16, 24, seed=3)
+    tables, idx, rows, bitpos, slots = _fused_case(2, 32, 16, w, seed=3)
     r_i, p_i = fused_expand_intersect_pallas(
-        tables, idx, rows, bitpos, slots=slots, words_per_block=wpb,
-        interpret=True)
+        tables, idx, rows, bitpos, slots=slots, interpret=True)
     r_c, p_c = fused_expand_intersect_pallas(
-        tables, idx, rows, bitpos, slots=slots, words_per_block=wpb,
-        interpret=False)
+        tables, idx, rows, bitpos, slots=slots, interpret=False)
     np.testing.assert_array_equal(np.asarray(r_c), np.asarray(r_i))
     np.testing.assert_array_equal(np.asarray(p_c), np.asarray(p_i))
 
@@ -140,32 +165,12 @@ def test_fused_ops_dispatch_and_two_step_reference():
     idxs = jnp.stack([cols[:, s] for s in slots], axis=1)
     two_step = ops.make_intersect_fn(use_pallas=False)
     r_ref, pop_ref = two_step(tables, idxs)
-    for kw in (dict(use_pallas=False), dict(use_pallas=True, interpret=True),
-               dict(use_pallas=True, interpret=True, words_per_block=16)):
+    for kw in (dict(use_pallas=False), dict(use_pallas=True, interpret=True)):
         r, pop = ops.fused_expand_intersect(tables, idx, rows, bitpos,
                                             slots=slots, **kw)
         np.testing.assert_array_equal(np.asarray(r), np.asarray(r_ref))
         np.testing.assert_array_equal(np.asarray(pop).ravel(),
                                       np.asarray(pop_ref).ravel())
-
-
-def test_autotune_words_per_block():
-    """Autotune returns one of the swept widths, caches per shape, and the
-    chosen width agrees with every other width bit-for-bit (so the choice
-    is a pure perf decision)."""
-    from repro.kernels.bitmap_intersect import (FUSED_TILE_WIDTHS,
-                                                autotune_words_per_block)
-    wb = autotune_words_per_block(2, 24, interpret=True)
-    assert wb in FUSED_TILE_WIDTHS
-    assert autotune_words_per_block(2, 24, interpret=True) == wb  # cached
-    tables, idx, rows, bitpos, slots = _fused_case(2, 16, 8, 24, seed=21)
-    outs = [fused_expand_intersect_pallas(tables, idx, rows, bitpos,
-                                          slots=slots, words_per_block=w)
-            for w in FUSED_TILE_WIDTHS]
-    for r, pop in outs[1:]:
-        np.testing.assert_array_equal(np.asarray(r), np.asarray(outs[0][0]))
-        np.testing.assert_array_equal(np.asarray(pop),
-                                      np.asarray(outs[0][1]))
 
 
 def test_engine_with_fused_intersect_matches_oracle():

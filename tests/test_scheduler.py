@@ -4,7 +4,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 from strategies import brother_workload
 
 from repro.core.engine import VectorEngine, vector_match
@@ -130,7 +129,7 @@ def test_tile_packing_parity():
 # ---------------------------------------------------------- on-device leaves
 def _device_leaf(singles, groups, terms, alive):
     red = make_leaf_reduce(singles, groups)
-    with enable_x64():
+    with jax.enable_x64(True):
         cnt, ovf = jax.jit(red)(jnp.asarray(terms, jnp.int32),
                                 jnp.asarray(alive, bool))
     return int(jax.device_get(cnt)), bool(jax.device_get(ovf))

@@ -18,8 +18,9 @@ from repro.core.ref_engine import cemr_match
 from repro.runtime.ft import FaultInjector
 from repro.runtime.queue import MatchQueueRuntime, QueryItem
 from repro.runtime.service import MatchService, ServiceConfig
-from repro.runtime.workers import (BucketResult, WorkerOutcome, WorkerPool,
-                                   as_triples)
+from repro.runtime.workers import (BucketResult, ChipPlacementError,
+                                   WorkerOutcome, WorkerPool, as_triples,
+                                   worker_chip_env)
 
 # real-process operations (spawn + jax import + first compile) get a
 # generous wall budget; the assertions below are on *behavior*, not speed
@@ -85,6 +86,36 @@ def test_as_triples_shapes():
 
 
 # -------------------------------------------------------------- pool basics
+@pytest.mark.parametrize("platform", ["cpu", "gpu"])
+def test_chip_placement_off_tpu_pins_nothing(platform):
+    """Off the TPU workers share the host: no environment, whatever the
+    chip count or the parent's state."""
+    assert worker_chip_env(platform, 0, 3, False) == [{}, {}, {}]
+    assert worker_chip_env(platform, 1, 2, True) == [{}, {}]
+
+
+@pytest.mark.parametrize("n_workers", [1, 2, 4])
+def test_chip_placement_pins_worker_i_to_chip_i(n_workers):
+    envs = worker_chip_env("tpu", 4, n_workers, False)
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == \
+        [str(i) for i in range(n_workers)]
+    assert all(e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+               and e["TPU_PROCESS_BOUNDS"] == "1,1,1" for e in envs)
+    ports = {e["TPU_PROCESS_PORT"] for e in envs}
+    assert len(ports) == n_workers          # no two workers share a port
+
+
+@pytest.mark.parametrize("n_chips,n_workers,holds,match", [
+    (4, 5, False, "5 workers but 4 TPU chips"),
+    (0, 1, False, "1 workers but 0 TPU chips"),
+    (4, 1, True, "already initialised the TPU backend"),
+    (4, 5, True, "already initialised the TPU backend"),
+])
+def test_chip_placement_refuses(n_chips, n_workers, holds, match):
+    with pytest.raises(ChipPlacementError, match=match):
+        worker_chip_env("tpu", n_chips, n_workers, holds)
+
+
 def test_pool_counts_bit_identical_to_oracle(pool, queries, expected):
     res = pool.run_sync(_items(queries))
     assert not res.worker_died
