@@ -33,6 +33,7 @@ from repro.core.filtering import CandidateSpace
 from repro.core.graph import Graph
 from repro.core.plan import build_plan
 from repro.core.ref_engine import cemr_match, preprocess
+from repro.core.spans import span
 
 from .dataset import Dataset
 from .options import BATCH_MODES, MatchOptions, auto_mesh_devices
@@ -52,14 +53,17 @@ class MatchOutcome:
 
     count: int
     engine: str                       # "ref" | "vector" (resolved)
-    elapsed_s: float                  # enumeration time (excludes compile)
+    elapsed_s: float                  # enumeration time (excludes compile;
+                                      # count's cemr.enumerate / cemr.host_dfs
+                                      # span)
     timed_out: bool
     stats: object                     # MatchStats (ref) | VectorStats (vector)
     embeddings: list[dict[int, int]] | None = None
     plan_cached: bool = False         # this call hit the plan cache
     compile_s: float = 0.0            # time this call spent compiling
                                       # (filtering + analysis + vector plan
-                                      # build; ~0 on a plan-cache hit)
+                                      # build; ~0 on a plan-cache hit;
+                                      # count's cemr.plan span)
     graph_version: int = 0            # Dataset.graph_version the count is
                                       # valid for (streaming datasets)
     engine_requested: str = ""        # the engine option as requested
@@ -393,59 +397,71 @@ class Matcher:
     def count(self, query: Graph, options: MatchOptions | None = None,
               **overrides) -> MatchOutcome:
         """Match `query`; returns a MatchOutcome (count + stats). Accepts a
-        full MatchOptions or keyword overrides of the Matcher defaults."""
+        full MatchOptions or keyword overrides of the Matcher defaults.
+        The call runs in the host span `cemr.count` (core/spans.py), whose
+        wall time lands in `stats.span_count_s`."""
         opts = self._resolve_options(options, overrides)
-        hits_before = self._hits
-        t0 = time.perf_counter()
-        cq = self.compile(query, opts)
-        cached = self._hits > hits_before
-        gv = self.dataset.graph_version
-        engine = cq.resolve_engine(opts.engine)
-        if engine == "vector" and not cq.empty:
-            _ = cq.plan               # force the lazy plan build (bitmap
+        with span("cemr.count", query=graph_signature(query)[:12]) as whole:
+            hits_before = self._hits
+            with span("cemr.plan") as plan:
+                cq = self.compile(query, opts)
+                engine = cq.resolve_engine(opts.engine)
+                if engine == "vector" and not cq.empty:
+                    _ = cq.plan       # force the lazy plan build (bitmap
                                       # tables) inside the compile_s window
-        compile_s = time.perf_counter() - t0
-        if cq.empty:
-            if engine == "ref":
-                from repro.core.ref_engine import MatchStats
-                stats = MatchStats()
+            whole.set_args(engine=engine)
+            cached = self._hits > hits_before
+            gv = self.dataset.graph_version
+            if cq.empty:
+                if engine == "ref":
+                    from repro.core.ref_engine import MatchStats
+                    stats = MatchStats()
+                else:
+                    from repro.core.engine import VectorStats
+                    stats = VectorStats()
+                out = MatchOutcome(count=0, engine=engine, elapsed_s=0.0,
+                                   timed_out=False, stats=stats,
+                                   embeddings=[] if opts.materialize
+                                   else None,
+                                   plan_cached=cached, compile_s=plan.seconds,
+                                   graph_version=gv,
+                                   engine_requested=opts.engine)
+            elif engine == "ref":
+                with span("cemr.host_dfs") as run:
+                    res = cemr_match(query, self.dataset.graph,
+                                     preprocessed=(cq.cs, cq.an),
+                                     use_cer=opts.use_cer,
+                                     use_cv=opts.use_cv, use_fs=opts.use_fs,
+                                     limit=opts.limit,
+                                     step_budget=opts.budget,
+                                     materialize=opts.materialize)
+                res.stats.span_host_dfs_s = run.seconds
+                out = MatchOutcome(count=res.count, engine="ref",
+                                   elapsed_s=run.seconds,
+                                   timed_out=res.timed_out, stats=res.stats,
+                                   embeddings=res.embeddings,
+                                   plan_cached=cached,
+                                   compile_s=plan.seconds, graph_version=gv,
+                                   engine_requested=opts.engine)
             else:
-                from repro.core.engine import VectorStats
-                stats = VectorStats()
-            out = MatchOutcome(count=0, engine=engine, elapsed_s=0.0,
-                               timed_out=False, stats=stats,
-                               embeddings=[] if opts.materialize else None,
-                               plan_cached=cached, compile_s=compile_s,
-                               graph_version=gv,
-                               engine_requested=opts.engine)
-        elif engine == "ref":
-            res = cemr_match(query, self.dataset.graph,
-                             preprocessed=(cq.cs, cq.an),
-                             use_cer=opts.use_cer, use_cv=opts.use_cv,
-                             use_fs=opts.use_fs, limit=opts.limit,
-                             step_budget=opts.budget,
-                             materialize=opts.materialize)
-            out = MatchOutcome(count=res.count, engine="ref",
-                               elapsed_s=res.elapsed_s,
-                               timed_out=res.timed_out, stats=res.stats,
-                               embeddings=res.embeddings, plan_cached=cached,
-                               compile_s=compile_s, graph_version=gv,
-                               engine_requested=opts.engine)
-        else:
-            eng = cq.vector_engine(
-                opts, intersect_fn=self._intersect_fn,
-                mesh=self._resolve_mesh(
-                    opts, total_rows=int(cq.cs.sizes().sum())))
-            t0 = time.perf_counter()
-            res = eng.run(limit=opts.limit, max_steps=opts.budget,
-                          materialize=opts.materialize)
-            out = MatchOutcome(count=res.count, engine="vector",
-                               elapsed_s=time.perf_counter() - t0,
-                               timed_out=res.timed_out, stats=res.stats,
-                               embeddings=res.embeddings, plan_cached=cached,
-                               compile_s=compile_s, graph_version=gv,
-                               engine_requested=opts.engine)
-        self._seed_standing(query, out, opts)
+                eng = cq.vector_engine(
+                    opts, intersect_fn=self._intersect_fn,
+                    mesh=self._resolve_mesh(
+                        opts, total_rows=int(cq.cs.sizes().sum())))
+                with span("cemr.enumerate") as run:
+                    res = eng.run(limit=opts.limit, max_steps=opts.budget,
+                                  materialize=opts.materialize)
+                res.stats.span_enumerate_s = run.seconds
+                out = MatchOutcome(count=res.count, engine="vector",
+                                   elapsed_s=run.seconds,
+                                   timed_out=res.timed_out, stats=res.stats,
+                                   embeddings=res.embeddings,
+                                   plan_cached=cached,
+                                   compile_s=plan.seconds, graph_version=gv,
+                                   engine_requested=opts.engine)
+            out.stats.span_plan_s = plan.seconds
+            self._seed_standing(query, out, opts)
+        out.stats.span_count_s = whole.seconds
         return out
 
     def _seed_standing(self, query: Graph, out: MatchOutcome,

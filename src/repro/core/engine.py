@@ -91,6 +91,16 @@ class VectorStats:
                                      # coalesces them: readbacks <= supersteps
     overlapped_supersteps: int = 0   # supersteps dispatched while an earlier
                                      # dispatch's readback was still outstanding
+    pad_copy_bytes: int = 0          # bytes of the zero-padded table copies the
+                                     # Pallas intersect kernel makes, charged per
+                                     # dispatch like gather_and_ops
+    # wall times of the host spans (core/spans.py), in seconds: timings, not
+    # counters, so equality of two stats compares the counters only
+    span_count_s: float = dataclasses.field(default=0.0, compare=False)
+    span_plan_s: float = dataclasses.field(default=0.0, compare=False)
+    span_enumerate_s: float = dataclasses.field(default=0.0, compare=False)
+    span_dispatch_s: float = dataclasses.field(default=0.0, compare=False)
+    span_readback_s: float = dataclasses.field(default=0.0, compare=False)
 
     @property
     def dedup_ratio(self) -> float:

@@ -53,6 +53,10 @@ class MatchStats:
     fs_skips: int = 0            # siblings skipped by failing-set backjumping
     leaves: int = 0
     peak_frontier_bytes: int = 0
+    # wall times of the host spans (core/spans.py), in seconds; not compared
+    span_count_s: float = dataclasses.field(default=0.0, compare=False)
+    span_plan_s: float = dataclasses.field(default=0.0, compare=False)
+    span_host_dfs_s: float = dataclasses.field(default=0.0, compare=False)
 
 
 @dataclasses.dataclass

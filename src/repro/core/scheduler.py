@@ -68,6 +68,7 @@ import numpy as np
 from . import bitops
 from .engine import VectorMatchResult, VectorStats
 from .plan import IDX, LevelOp
+from .spans import span
 
 __all__ = ["TileScheduler", "SuperbatchScheduler", "BatchProgram",
            "leaf_count_host", "make_leaf_reduce", "make_leaf_reduce_batched",
@@ -398,7 +399,8 @@ def _sync_inflight(st, inflight):
     fused loops. A coalesced readback of N overlapped supersteps counts as
     one `readbacks` and N-1 `overlapped_supersteps`, which is what makes
     `readbacks <= supersteps` the overlap accounting invariant."""
-    outs = jax.device_get([p["sync"] for p in inflight])
+    with span("cemr.readback", st, "span_readback_s"):
+        outs = jax.device_get([p["sync"] for p in inflight])
     for p, o in zip(inflight, outs):
         p["np"] = o
     st.readbacks += 1
@@ -498,11 +500,13 @@ class TileScheduler:
         ladder stopped.
 
         Returns (step, exit_bounds, seg_cer, seg_fail, n_computes,
-        gather_ops). The step takes an optional trailing `part` bitmap
-        (root_words,) that is ANDed into the root extension — the sharded
-        scheduler's per-shard partition of the level-0 candidate rows;
-        `part=None` (the single-device path) leaves the root mask
-        untouched."""
+        gather_ops, pad_bytes); `pad_bytes` counts the padded table copies
+        the Pallas kernels make in one dispatch. The step is named
+        `cemr_superstep_b<b>`, which names its jitted program. It takes an
+        optional trailing `part` bitmap (root_words,) that is ANDed into
+        the root extension — the sharded scheduler's per-shard partition
+        of the level-0 candidate rows; `part=None` (the single-device
+        path) leaves the root mask untouched."""
         eng = self.eng
         t = self.t
         cer_set = set(self._cer_stages)
@@ -512,7 +516,10 @@ class TileScheduler:
         built = []                                       # per-segment closures
         seg_cer: list = []
         fail_seg: dict = {}               # fail stage -> computing segment
+        from repro.kernels.bitmap_intersect import pad_copy_bytes
         gather_ops = 0
+        pad_bytes = 0
+        pallas = getattr(eng.intersect_fn, "pallas", False)
         n_computes = 0
         for ki, (si, bms, exit_si) in enumerate(segs):
             leaf_i = exit_si == self._n_stages
@@ -531,6 +538,14 @@ class TileScheduler:
             # the engine runs with intersect="fused" and the pair is
             # eligible; None composes the plain expand + per-stage computes
             fused0 = eng._make_expand_fused(si, chain[0][0]) if chain else None
+            # extends with backward pairs call a Pallas kernel: the fused
+            # one for the segment's first, the intersect kernel otherwise
+            for ci, (sj, op, _, _) in enumerate(chain):
+                if (eng._stages[sj][0] == "extend" and op.bk_pairs
+                        and (pallas or (ci == 0 and fused0 is not None))):
+                    pad_bytes += pad_copy_bytes(
+                        eng.tables[f"{u}:{op.vertex}"].shape
+                        for (_, u) in op.bk_pairs)
             built.append((eng._make_expand(si), chain, leaf_i, fused0))
         n_bounds_before = sum(1 for j in range(b) if self._is_boundary(j))
         fail_by_seg = _fail_plan(segs, n_bounds_before, fail_seg,
@@ -655,8 +670,9 @@ class TileScheduler:
                 proceed = ok_here if proceed is None else (proceed & ok_here)
                 cur_tile, cur_r, cur_cursor = cur, r2, jnp.int32(0)
 
+        step.__name__ = step.__qualname__ = f"cemr_superstep_b{b}"
         return (step, exit_bounds, sorted(set(seg_cer)), seg_fail,
-                n_computes, gather_ops)
+                n_computes, gather_ops, pad_bytes)
 
     def _superstep(self, b: int):
         """Cached jitted wrapper of `_build_step(b)` — one device dispatch
@@ -664,10 +680,8 @@ class TileScheduler:
         key = ("ss", b)
         if key in self._jit:
             return self._jit[key]
-        step, exit_bounds, seg_cer, seg_fail, n_computes, gather_ops = \
-            self._build_step(b)
-        entry = (jax.jit(step), exit_bounds, seg_cer, seg_fail, n_computes,
-                 gather_ops)
+        step, *static = self._build_step(b)
+        entry = (jax.jit(step), *static)
         self._jit[key] = entry
         return entry
 
@@ -680,7 +694,7 @@ class TileScheduler:
             return self._jit[key]
         t = self.t
 
-        def merge(ta, ra, tb, rb):
+        def cemr_merge(ta, ra, tb, rb):
             idx = jnp.concatenate([ta["idx"], tb["idx"]])
             bm = {u: jnp.concatenate([ta["bm"][u], tb["bm"][u]])
                   for u in ta["bm"]}
@@ -692,7 +706,7 @@ class TileScheduler:
                     "alive": live[order]}
             return tile, r[order]
 
-        fn = jax.jit(merge)
+        fn = jax.jit(cemr_merge)
         self._jit[key] = fn
         return fn
 
@@ -742,32 +756,34 @@ class TileScheduler:
         eng = self.eng
         st = self.stats
         b, tile, r, cursor, tot = item
-        fn, exit_bounds, seg_cer, seg_fail, n_computes, gather_ops = \
-            self._superstep(b)
-        bufs = {si: self._buffers[si] for si in seg_cer}
-        fbufs = {si: self._fail_buffers[si] for si in seg_fail}
-        with jax.enable_x64(True):                   # leaf reduce is int64
-            (leaf_tile, terms, cnt, ovf, packed, frontiers, bufs2,
-             fbufs2) = fn(tile, r, jnp.int32(cursor), bufs, fbufs,
-                          eng.tables, eng.masks)
-        for si in seg_cer:
-            self._buffers[si] = bufs2[si]
-        for si in seg_fail:
-            self._fail_buffers[si] = fbufs2[si]
-        if self.fail_debug_hook is not None:
-            self.fail_debug_hook(self)
-        st.device_steps += 1
-        st.supersteps += 1
-        st.tiles += 1
-        st.expansions += 1
-        st.rows_processed += self.t * max(n_computes, 1)
-        st.gather_and_ops += gather_ops
-        if tot >= 0 and cursor + self.t < tot:
-            stack.append((b, tile, r, cursor + self.t, tot))
-        return {"item": item, "exit_bounds": exit_bounds,
-                "leaf_tile": leaf_tile, "terms": terms,
-                "frontiers": frontiers, "sync": (packed, cnt, ovf),
-                "np": None}
+        with span("cemr.dispatch", st, "span_dispatch_s", boundary=b):
+            (fn, exit_bounds, seg_cer, seg_fail, n_computes, gather_ops,
+             pad_bytes) = self._superstep(b)
+            bufs = {si: self._buffers[si] for si in seg_cer}
+            fbufs = {si: self._fail_buffers[si] for si in seg_fail}
+            with jax.enable_x64(True):               # leaf reduce is int64
+                (leaf_tile, terms, cnt, ovf, packed, frontiers, bufs2,
+                 fbufs2) = fn(tile, r, jnp.int32(cursor), bufs, fbufs,
+                              eng.tables, eng.masks)
+            for si in seg_cer:
+                self._buffers[si] = bufs2[si]
+            for si in seg_fail:
+                self._fail_buffers[si] = fbufs2[si]
+            if self.fail_debug_hook is not None:
+                self.fail_debug_hook(self)
+            st.device_steps += 1
+            st.supersteps += 1
+            st.tiles += 1
+            st.expansions += 1
+            st.rows_processed += self.t * max(n_computes, 1)
+            st.gather_and_ops += gather_ops
+            st.pad_copy_bytes += pad_bytes
+            if tot >= 0 and cursor + self.t < tot:
+                stack.append((b, tile, r, cursor + self.t, tot))
+            return {"item": item, "exit_bounds": exit_bounds,
+                    "leaf_tile": leaf_tile, "terms": terms,
+                    "frontiers": frontiers, "sync": (packed, cnt, ovf),
+                    "np": None}
 
     def _process_fused(self, p, stack, pending, embeddings, materialize,
                        limit):
@@ -869,8 +885,10 @@ class TileScheduler:
             if eng.overlap:
                 _sync_inflight(st, inflight)
             for p in inflight:
-                count += self._process_fused(p, stack, pending, embeddings,
-                                             materialize, limit)
+                with span("cemr.process"):
+                    count += self._process_fused(p, stack, pending,
+                                                 embeddings, materialize,
+                                                 limit)
                 if count >= limit:
                     break
             if count >= limit:

@@ -158,7 +158,7 @@ class _ShardLoopBase:
             self._shard_jit = {}
         if b in self._shard_jit:
             return self._shard_jit[b]
-        step, exit_bounds, seg_cer, seg_fail, n_computes, gather_ops = \
+        step, exit_bounds, seg_cer, seg_fail, n_computes, gather_ops, *_ = \
             self._lane_step(b)
 
         def body(tile, r, cursor, bufs, fbufs, part, aux1, aux2):

@@ -35,10 +35,24 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["bitmap_intersect_pallas", "fused_expand_intersect_pallas",
-           "ROWS_PER_STEP", "LANES"]
+           "pad_copy_bytes", "ROWS_PER_STEP", "LANES", "KERNEL_NAME"]
 
 ROWS_PER_STEP = 8     # frontier rows per grid step: one 8-sublane output tile
 LANES = 128           # W is padded to a multiple of the lane width
+KERNEL_NAME = "cemr_gather_and"   # the kernel's name in HLO and device traces
+
+
+def pad_copy_bytes(shapes) -> int:
+    """Bytes of the zero-padded table copies one kernel call makes for
+    tables of these (S, W) shapes: S * W_pad * 4 for each table whose W is
+    not a multiple of LANES (`jnp.pad` writes the whole padded table), 0
+    for the others."""
+    total = 0
+    for s, w in shapes:
+        w_pad = pl.cdiv(w, LANES) * LANES
+        if w_pad > w:
+            total += s * w_pad * 4
+    return total
 
 
 def _clamp_index(i, n: int):
@@ -97,12 +111,12 @@ def _gather_and(tables: tuple, prefetch: tuple, t_rows: int, resolve,
                                tuple(tbl.shape[0] for tbl in tables))
     # the kernel is all 32-bit; traced under the caller's x64 (the leaf
     # supersteps) its scalar indices would become i64, which Mosaic refuses
-    with jax.enable_x64(False):
+    with jax.enable_x64(False), jax.named_scope(KERNEL_NAME):
         r, pop = pl.pallas_call(
             kernel, grid_spec=gs,
             out_shape=(jax.ShapeDtypeStruct((t_pad, w_pad), jnp.uint32),
                        jax.ShapeDtypeStruct((t_pad, 1), jnp.int32)),
-            interpret=interpret)(*prefetch, *rows3)
+            interpret=interpret, name=KERNEL_NAME)(*prefetch, *rows3)
     return r[:t_rows, :w], pop[:t_rows]
 
 

@@ -90,13 +90,16 @@ def make_intersect_fn(*, use_pallas: bool = True, interpret: bool | None = None)
     """Adapter for core.engine.VectorEngine(intersect_fn=...): takes the list
     of gathered tables + (T, k) indices and returns ``(R, pop)`` — the ANDed
     bitmap *and* the kernel's fused per-row popcount ((T,) int32), so the
-    engine's contained-vertex prune never re-reduces R."""
+    engine's contained-vertex prune never re-reduces R. `fn.pallas` says
+    whether it calls the Pallas kernel (the engine counts the kernel's
+    padded table copies by it)."""
 
     def fn(tables, idxs):
         r, pop = bitmap_intersect(tables, idxs, use_pallas=use_pallas,
                                   interpret=interpret)
         return r, pop.reshape(-1)
 
+    fn.pallas = use_pallas
     return fn
 
 

@@ -37,13 +37,15 @@ OVERLAP_COUNTERS = ("readbacks", "overlapped_supersteps")
 
 
 def stats_mod_overlap(st, *, warmth=False):
-    """VectorStats as a dict with the overlap-timing counters removed —
+    """VectorStats as a dict with the overlap-timing counters and the host
+    span timings (`span_*_s`, wall times, never equal twice) removed —
     every remaining field must be bit-identical across overlap on/off.
     `warmth=True` also drops `bucket_recompiles`: superbatch programs are
     shared through a module-level jit cache keyed without overlap (the
     program is overlap-agnostic by design), so whichever run goes second
     inherits warm traces and legitimately reports fewer recompiles."""
-    d = dataclasses.asdict(st)
+    d = {k: v for k, v in dataclasses.asdict(st).items()
+         if not k.startswith("span_")}
     for k in OVERLAP_COUNTERS:
         d.pop(k)
     if warmth:

@@ -12,13 +12,16 @@ columns. Nothing runs, so results are checked by the interpret-mode tests.
 The topology is described inside a module fixture only: describing it
 loads the TPU library, which one process at a time may hold.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.scheduler import make_leaf_reduce, make_leaf_reduce_batched
-from repro.kernels.bitmap_intersect import (bitmap_intersect_pallas,
+from repro.kernels.bitmap_intersect import (KERNEL_NAME,
+                                            bitmap_intersect_pallas,
                                             fused_expand_intersect_pallas)
 
 TABLE_ROWS = 4861          # largest single table of the full-scale queries
@@ -86,6 +89,27 @@ def test_fused_expand_intersect_without_parent_columns_compiles(one_chip):
     sel = _spec(one_chip, (TILE_ROWS,), jnp.int32)
     fused_expand_intersect_pallas.lower(tables, idx, sel, sel, slots=(0,),
                                         interpret=False).compile()
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_kernel_carries_its_name_for_v5e(one_chip, fused):
+    """Both entry points lower to one custom call named `cemr_gather_and`,
+    the name its op carries in a device trace."""
+    tables = tuple(_spec(one_chip, (TABLE_ROWS - j, 124), jnp.uint32)
+                   for j in range(2))
+    if fused:
+        idx = _spec(one_chip, (TILE_ROWS, PARENT_COLS), jnp.int32)
+        sel = _spec(one_chip, (TILE_ROWS,), jnp.int32)
+        lowered = fused_expand_intersect_pallas.lower(
+            tables, idx, sel, sel, slots=(PARENT_COLS, 0), interpret=False)
+    else:
+        idxs = _spec(one_chip, (TILE_ROWS, 2), jnp.int32)
+        lowered = bitmap_intersect_pallas.lower(tables, idxs,
+                                                interpret=False)
+    calls = [line for line in lowered.compile().as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    assert re.match(rf"\s*%{KERNEL_NAME}(\.\d+)? = ", calls[0]), calls[0]
 
 
 @pytest.mark.parametrize("batched", [False, True])
