@@ -49,7 +49,7 @@ from .plan import (BM, IDX, INTERSECT_MODES, LevelOp, MatchingPlan,
 from .ref_engine import preprocess
 
 __all__ = ["VectorMatchResult", "VectorStats", "vector_match", "VectorEngine",
-           "INTERSECT_MODES"]
+           "TablePack", "INTERSECT_MODES"]
 
 
 @dataclasses.dataclass
@@ -94,6 +94,9 @@ class VectorStats:
     pad_copy_bytes: int = 0          # bytes of the zero-padded table copies the
                                      # Pallas intersect kernel makes, charged per
                                      # dispatch like gather_and_ops
+    dispatch_buffers: int = 0        # flattened input plus output leaves of
+                                     # every superstep call (device buffers the
+                                     # runtime takes in and hands back)
     # wall times of the host spans (core/spans.py), in seconds: timings, not
     # counters, so equality of two stats compares the counters only
     span_count_s: float = dataclasses.field(default=0.0, compare=False)
@@ -114,6 +117,48 @@ class VectorMatchResult:
     stats: VectorStats
     timed_out: bool
     embeddings: list[dict[int, int]] | None = None
+
+
+class TablePack:
+    """A plan's adjacency tables and candidate masks, packed at engine build
+    into one 2-D uint32 device buffer per word width W (`groups`, widths
+    ascending): the rows of every table of width W, then every mask of width
+    W as one more row each. `table_at["u:w"] = (group, row offset, rows)`
+    and `mask_at[u] = (group, row)` are fixed here, so a jitted step takes
+    a handful of buffers and slices its tables out statically (`view`)."""
+
+    def __init__(self, tables: dict, masks: dict):
+        stacks: dict[int, list] = {}              # width -> stacked arrays
+
+        def put(arr):                             # -> (width, row offset)
+            parts = stacks.setdefault(arr.shape[1], [])
+            parts.append(arr)
+            return arr.shape[1], sum(a.shape[0] for a in parts[:-1])
+
+        tab = {f"{u}:{w}": (*put(t), t.shape[0])
+               for (u, w), t in tables.items()}
+        msk = {u: put(m[None, :]) for u, m in masks.items()}
+        self.widths = sorted(stacks)
+        g_of = {w: g for g, w in enumerate(self.widths)}
+        self.table_at = {k: (g_of[w], o, n) for k, (w, o, n) in tab.items()}
+        self.mask_at = {u: (g_of[w], o) for u, (w, o) in msk.items()}
+        self.groups = tuple(
+            jnp.asarray(np.concatenate(stacks[w]).astype(np.uint32))
+            for w in self.widths)
+
+    def shape(self, key: str) -> tuple[int, int]:
+        """(rows, words) of table `key`."""
+        g, _, n = self.table_at[key]
+        return n, self.widths[g]
+
+    def view(self, groups):
+        """Trace-time (tables, masks) dicts over `groups` (this pack's
+        buffers, as the jitted step receives them): static row slices under
+        the plan's keys, which the stage closures read as before."""
+        tables = {k: groups[g][o:o + n]
+                  for k, (g, o, n) in self.table_at.items()}
+        masks = {u: groups[g][o] for u, (g, o) in self.mask_at.items()}
+        return tables, masks
 
 
 # ---------------------------------------------------------------------------
@@ -194,8 +239,8 @@ class VectorEngine:
             intersect_fn = _resolve_intersect_fn(intersect)
         self.intersect_fn = intersect_fn  # pluggable kernel (Pallas ops)
         p = self.plan
-        self.tables = {f"{u}:{w}": jnp.asarray(t) for (u, w), t in p.tables.items()}
-        self.masks = {u: jnp.asarray(m) for u, m in p.masks.items()}
+        # the only device copy of the tables and masks (TablePack.view)
+        self.packed = TablePack(p.tables, p.masks)
         self.stats = VectorStats()
         self._stages = self._build_stages()
         self._jit_cache: dict = {}
@@ -410,8 +455,8 @@ class VectorEngine:
             return self._jit_cache[key]
         compute_r, con = self._make_compute_parts(si)
 
-        def compute(tile, tables, masks):
-            r, pop = compute_r(tile, tables, masks)
+        def compute(tile, groups):
+            r, pop = compute_r(tile, *self.packed.view(groups))
             r, pop, ok = self.finish_compute(tile, r, pop, con)
             return r, ok
 
@@ -423,7 +468,12 @@ class VectorEngine:
         key = ("expand", si)
         if key in self._jit_cache:
             return self._jit_cache[key]
-        fn = jax.jit(self._make_expand(si))
+        expand = self._make_expand(si)
+
+        def expand_packed(tile, r, start, groups):
+            return expand(tile, r, start, self.packed.view(groups)[0])
+
+        fn = jax.jit(expand_packed)
         self._jit_cache[key] = fn
         return fn
 
@@ -484,7 +534,8 @@ class VectorEngine:
         pairs = [(s, u, op.vertex) for (s, u) in op.bk_pairs]
         con = max(op.con_threshold, 1) if self.use_cv else 1
 
-        def compute(tile, rep_rows, group_of, tables):
+        def compute(tile, rep_rows, group_of, groups):
+            tables, _ = self.packed.view(groups)
             reps = rep_rows[:bucket]
             idx_b = tile["idx"][reps]
             alive_b = tile["alive"][reps]

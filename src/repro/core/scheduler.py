@@ -200,6 +200,38 @@ def _init_cer_buffer(n_slots: int, key_width: int, n_words: int):
     }
 
 
+def _pack_cer(buf):
+    """A CER buffer as one uint32 record (n_slots + 1, K + 3 + n_words):
+    columns keys (K), hash, pops, valid, then the vals words; the extra
+    last row holds `ptr` in column 0. Int32 fields are bit-cast, so
+    `_unpack_cer` restores every field exactly."""
+    u32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.uint32)  # noqa: E731
+    body = jnp.concatenate(
+        [u32(buf["keys"]), u32(buf["hash"])[:, None],
+         u32(buf["pops"])[:, None], buf["valid"].astype(jnp.uint32)[:, None],
+         buf["vals"]], axis=1)
+    tail = jnp.zeros((1, body.shape[1]), jnp.uint32).at[0, 0].set(
+        u32(buf["ptr"]))
+    return jnp.concatenate([body, tail])
+
+
+def _unpack_cer(rec, key_width: int):
+    """The dict `_cer_compute` takes, from a `_pack_cer` record."""
+    i32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.int32)  # noqa: E731
+    k, body = key_width, rec[:-1]
+    return {"keys": i32(body[:, :k]), "hash": i32(body[:, k]),
+            "pops": i32(body[:, k + 1]), "valid": body[:, k + 2] != 0,
+            "vals": body[:, k + 3:], "ptr": i32(rec[-1, 0])}
+
+
+def _empty_cer_record(n_slots: int, key_width: int, n_words: int):
+    """`_pack_cer(_init_cer_buffer(...))`, built on the host (no eager
+    device ops to lower): keys and hash -1, every other field 0."""
+    rec = np.zeros((n_slots + 1, key_width + 3 + n_words), np.uint32)
+    rec[:-1, :key_width + 1] = np.uint32(0xFFFFFFFF)
+    return jnp.asarray(rec)
+
+
 def _cer_compute(keys, compute, tile, buf):
     """Buffered extension compute for one CER-enabled stage.
 
@@ -311,6 +343,33 @@ def _init_fail_buffer(n_slots: int, key_width: int):
     }
 
 
+def _pack_fail(buf):
+    """A failure buffer as one int32 record (n_slots + 1, K + 3): columns
+    keys (K), hash, wit, valid; the extra last row holds `ptr` in column
+    0."""
+    body = jnp.concatenate(
+        [buf["keys"], buf["hash"][:, None], buf["wit"][:, None],
+         buf["valid"].astype(jnp.int32)[:, None]], axis=1)
+    tail = jnp.zeros((1, body.shape[1]), jnp.int32).at[0, 0].set(buf["ptr"])
+    return jnp.concatenate([body, tail])
+
+
+def _unpack_fail(rec):
+    """The dict `_fail_lookup` and `_fail_insert` take, from a `_pack_fail`
+    record."""
+    body = rec[:-1]
+    return {"keys": body[:, :-3], "hash": body[:, -3], "wit": body[:, -2],
+            "valid": body[:, -1] != 0, "ptr": rec[-1, 0]}
+
+
+def _empty_fail_record(n_slots: int, key_width: int):
+    """`_pack_fail(_init_fail_buffer(...))`, built on the host: keys and
+    hash -1, every other field 0."""
+    rec = np.zeros((n_slots + 1, key_width + 3), np.int32)
+    rec[:-1, :key_width + 1] = -1
+    return jnp.asarray(rec)
+
+
 def _fail_hash(keys):
     """Row-wise fold of the key columns (same polynomial as _cer_compute)."""
     h = jnp.zeros(keys.shape[0], jnp.int32)
@@ -393,6 +452,16 @@ def _fail_plan(segs, n_bounds_before, fail_seg, slots_of):
     return fail_by_seg
 
 
+def _charge_buffers(st, cache: dict, b, args, outs):
+    """Charge one superstep call's flattened input plus output leaves to
+    `st.dispatch_buffers`. Their number is fixed per boundary program, so
+    it is counted on the program's first call and cached in `cache`."""
+    n = cache.get(b)
+    if n is None:
+        n = cache[b] = len(jax.tree.leaves((args, outs)))
+    st.dispatch_buffers += n
+
+
 def _sync_inflight(st, inflight):
     """Synchronize in-flight superstep dispatches: one `jax.device_get`
     over every record's `sync` tuple — the only host sync point of the
@@ -424,17 +493,20 @@ class TileScheduler:
         self._jit: dict = {}
         self._cer_stages = [si for si in range(self._n_stages)
                             if self._cer_eligible(si)]
+        # ring buffers live between supersteps as one packed record per
+        # stage (`_pack_cer` / `_pack_fail`): one device buffer each
         self._buffers = {}
         for si in self._cer_stages:
             op = eng._stages[si][1]
-            self._buffers[si] = _init_cer_buffer(
+            self._buffers[si] = _empty_cer_record(
                 eng.cer_buffer_slots, len(op.dedup_slots), op.n_words)
         self._fail_stages = [si for si in range(self._n_stages)
                              if self._fail_eligible(si)]
         self._fail_buffers = {
-            si: _init_fail_buffer(eng.failure_cache_slots,
-                                  len(eng._stages[si][1].dedup_slots))
+            si: _empty_fail_record(eng.failure_cache_slots,
+                                   len(eng._stages[si][1].dedup_slots))
             for si in self._fail_stages}
+        self._n_buffers: dict = {}       # boundary -> leaves per call
         # test hook: called with the scheduler after every superstep's
         # buffer fold-back (tests corrupt _fail_buffers mid-run through it)
         self.fail_debug_hook = None
@@ -502,11 +574,15 @@ class TileScheduler:
         Returns (step, exit_bounds, seg_cer, seg_fail, n_computes,
         gather_ops, pad_bytes); `pad_bytes` counts the padded table copies
         the Pallas kernels make in one dispatch. The step is named
-        `cemr_superstep_b<b>`, which names its jitted program. It takes an
-        optional trailing `part` bitmap (root_words,) that is ANDed into
-        the root extension — the sharded scheduler's per-shard partition
-        of the level-0 candidate rows; `part=None` (the single-device
-        path) leaves the root mask untouched."""
+        `cemr_superstep_b<b>`, which names its jitted program. It takes
+        `(tile, r, cursor, bufs, fbufs, groups)`: the ring buffers as one
+        packed record per stage of the ladder (`{stage: record}`, returned
+        updated) and the engine's `TablePack.groups`, which it reads
+        through `TablePack.view`. An optional trailing `part` bitmap
+        (root_words,) is ANDed into the root extension — the sharded
+        scheduler's per-shard partition of the level-0 candidate rows;
+        `part=None` (the single-device path) leaves the root mask
+        untouched."""
         eng = self.eng
         t = self.t
         cer_set = set(self._cer_stages)
@@ -544,7 +620,7 @@ class TileScheduler:
                 if (eng._stages[sj][0] == "extend" and op.bk_pairs
                         and (pallas or (ci == 0 and fused0 is not None))):
                     pad_bytes += pad_copy_bytes(
-                        eng.tables[f"{u}:{op.vertex}"].shape
+                        eng.packed.shape(f"{u}:{op.vertex}")
                         for (_, u) in op.bk_pairs)
             built.append((eng._make_expand(si), chain, leaf_i, fused0))
         n_bounds_before = sum(1 for j in range(b) if self._is_boundary(j))
@@ -607,9 +683,13 @@ class TileScheduler:
             cur["alive"] = alive0 & ~dead
             facc[3] = facc[3] + dead.sum().astype(jnp.int32)
 
-        def step(tile, r_in, cursor, bufs, fbufs, tables, masks, part=None):
-            bufs = dict(bufs)
-            fbufs = dict(fbufs)
+        cer_k = {si: len(eng._stages[si][1].dedup_slots) for si in seg_cer}
+
+        def step(tile, r_in, cursor, bufs, fbufs, groups, part=None):
+            tables, masks = eng.packed.view(groups)
+            bufs = {si: _unpack_cer(rec, cer_k[si])
+                    for si, rec in bufs.items()}
+            fbufs = {si: _unpack_fail(rec) for si, rec in fbufs.items()}
             acc = [jnp.int32(0)] * 4                     # hits/misses/seen/ins
             facc = [jnp.int32(0)] * 4                    # fail h/m/ins/pruned
             if root:
@@ -659,7 +739,8 @@ class TileScheduler:
                         [total_in, leaf_alive, *alive_l, *total_l, *acc,
                          *facc])
                     return (cur, terms, count, overflow, packed, frontiers,
-                            bufs, fbufs)
+                            {si: _pack_cer(v) for si, v in bufs.items()},
+                            {si: _pack_fail(v) for si, v in fbufs.items()})
                 r2, pop2, ok2 = last
                 alive_k = ok2.sum().astype(jnp.int32)
                 total_k = jnp.sum(pop2, dtype=jnp.int32)
@@ -759,12 +840,16 @@ class TileScheduler:
         with span("cemr.dispatch", st, "span_dispatch_s", boundary=b):
             (fn, exit_bounds, seg_cer, seg_fail, n_computes, gather_ops,
              pad_bytes) = self._superstep(b)
-            bufs = {si: self._buffers[si] for si in seg_cer}
-            fbufs = {si: self._fail_buffers[si] for si in seg_fail}
+            # the cursor goes in as a host scalar: no eager device upload
+            args = (tile, r, np.int32(cursor),
+                    {si: self._buffers[si] for si in seg_cer},
+                    {si: self._fail_buffers[si] for si in seg_fail},
+                    eng.packed.groups)
             with jax.enable_x64(True):               # leaf reduce is int64
-                (leaf_tile, terms, cnt, ovf, packed, frontiers, bufs2,
-                 fbufs2) = fn(tile, r, jnp.int32(cursor), bufs, fbufs,
-                              eng.tables, eng.masks)
+                outs = fn(*args)
+            (leaf_tile, terms, cnt, ovf, packed, frontiers, bufs2,
+             fbufs2) = outs
+            _charge_buffers(st, self._n_buffers, b, args, outs)
             for si in seg_cer:
                 self._buffers[si] = bufs2[si]
             for si in seg_fail:
@@ -965,7 +1050,7 @@ class TileScheduler:
                 rows = int(tile["alive"].shape[0])
                 st.rows_processed += rows
                 if stage[0] == "decompose":
-                    r, ok = eng._compute_fn(si)(tile, eng.tables, eng.masks)
+                    r, ok = eng._compute_fn(si)(tile, eng.packed.groups)
                     st.device_steps += 1
                     stack.append(("expand", si, tile, r, 0))
                 else:
@@ -983,15 +1068,15 @@ class TileScheduler:
                             bucket = 1 << max(u - 1, 1).bit_length()
                             bucket = min(bucket, rows)
                             r, ok = eng._bucket_compute_fn(si, bucket)(
-                                tile, rep_rows, group_of, eng.tables)
+                                tile, rep_rows, group_of, eng.packed.groups)
                             st.device_steps += 1
                             st.bucketed_tiles += 1
                             st.gather_and_ops += bucket * len(op.bk_pairs)
                             bucketed = True
                     if not bucketed:
                         st.gather_and_ops += rows * max(len(op.bk_pairs), 1)
-                        r, ok = eng._compute_fn(si)(tile, eng.tables,
-                                                    eng.masks)
+                        r, ok = eng._compute_fn(si)(tile,
+                                                    eng.packed.groups)
                         st.device_steps += 1
                     if op.store == IDX:
                         stack.append(("expand", si, tile, r, 0))
@@ -1004,8 +1089,8 @@ class TileScheduler:
             else:
                 _, si, tile, r, cursor = item
                 st.expansions += 1
-                out, total = eng._expand_fn(si)(tile, r, jnp.int32(cursor),
-                                                eng.tables)
+                out, total = eng._expand_fn(si)(tile, r, np.int32(cursor),
+                                                eng.packed.groups)
                 st.device_steps += 1
                 total = int(total)
                 if cursor + t < total:

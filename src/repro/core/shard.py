@@ -59,8 +59,8 @@ from repro.distributed.sharding import partition_bitmap
 
 from .engine import VectorMatchResult, VectorStats
 from .plan import root_extension_weights
-from .scheduler import (SuperbatchScheduler, TileScheduler, _sync_inflight,
-                        leaf_count_host)
+from .scheduler import (SuperbatchScheduler, TileScheduler, _charge_buffers,
+                        _sync_inflight, leaf_count_host)
 
 __all__ = ["ShardedTileScheduler", "ShardedSuperbatchScheduler"]
 
@@ -148,12 +148,12 @@ class _ShardLoopBase:
         else:
             stack.append(self._item(b, tile, r, 0, total))
 
-    def _shard_fn(self, b: int):
+    def _shard_fn(self, b: int, n_aux: int):
         """Cached shard_map-wrapped superstep for boundary `b`: every lane
         runs the same ladder step on its own tile / cursor / partition /
-        CER buffers; the two trailing step arguments (tables+masks, or
-        stacked data+active) are replicated; the leaf count is
-        psum-reduced across the "data" axis."""
+        CER buffers; the `n_aux` trailing step arguments (the table
+        groups, or stacked data and active) are replicated; the leaf count
+        is psum-reduced across the "data" axis."""
         if not hasattr(self, "_shard_jit"):
             self._shard_jit = {}
         if b in self._shard_jit:
@@ -161,11 +161,11 @@ class _ShardLoopBase:
         step, exit_bounds, seg_cer, seg_fail, n_computes, gather_ops, *_ = \
             self._lane_step(b)
 
-        def body(tile, r, cursor, bufs, fbufs, part, aux1, aux2):
+        def body(tile, r, cursor, bufs, fbufs, part, *aux):
             sq = lambda tr: jax.tree.map(lambda x: x[0], tr)  # noqa: E731
             (leaf_tile, terms, cnt, ovf, packed, frontiers, bufs2,
              fbufs2) = step(sq(tile), r[0], cursor[0], sq(bufs), sq(fbufs),
-                            aux1, aux2, part=part[0])
+                            *aux, part=part[0])
             total = jax.lax.psum(cnt, "data")
             ex = lambda tr: jax.tree.map(lambda x: x[None], tr)  # noqa: E731
             return (ex(leaf_tile), terms[None], cnt[None], ovf[None],
@@ -174,14 +174,14 @@ class _ShardLoopBase:
 
         fn = jax.jit(jax.shard_map(
             body, mesh=self.mesh,
-            in_specs=(_SH, _SH, _SH, _SH, _SH, _SH, P(), P()),
+            in_specs=(_SH,) * 6 + (P(),) * n_aux,
             out_specs=(_SH, _SH, _SH, _SH, _SH, _SH, _SH, _SH, P()),
             check_vma=False))
         entry = (fn, exit_bounds, seg_cer, seg_fail, n_computes, gather_ops)
         self._shard_jit[b] = entry
         return entry
 
-    def _dispatch(self, b, lanes, aux1, aux2):
+    def _dispatch(self, b, lanes, *aux):
         """Pad `lanes` to the mesh width and run one sharded superstep
         *without waiting for its readback*. The CER / failure-cache
         buffers fold forward as asynchronous device values and the
@@ -195,23 +195,25 @@ class _ShardLoopBase:
         while len(lanes) < S:
             lanes.append(self._dead_item(lanes[0]))
         (fn, exit_bounds, seg_cer, seg_fail, n_computes,
-         gather_ops) = self._shard_fn(b)
-        tiles = _lane_stack([l[1] for l in lanes])
-        rs = jnp.stack([l[2] for l in lanes])
-        cursors = jnp.asarray([l[3] for l in lanes], dtype=jnp.int32)
-        parts = jnp.stack([l[5] for l in lanes])
-        bufs = {si: self._buffers[si] for si in seg_cer}
-        fbufs = {si: self._fail_buffers[si] for si in seg_fail}
+         gather_ops) = self._shard_fn(b, len(aux))
+        args = (_lane_stack([l[1] for l in lanes]),
+                jnp.stack([l[2] for l in lanes]),
+                np.asarray([l[3] for l in lanes], dtype=np.int32),
+                {si: self._buffers[si] for si in seg_cer},
+                {si: self._fail_buffers[si] for si in seg_fail},
+                jnp.stack([l[5] for l in lanes]), *aux)
         with jax.enable_x64(True):                   # leaf reduce is int64
-            (leaf_tile, terms, cnt, ovf, packed, frontiers, bufs2, fbufs2,
-             total) = fn(tiles, rs, cursors, bufs, fbufs, parts, aux1, aux2)
+            outs = fn(*args)
+        (leaf_tile, terms, cnt, ovf, packed, frontiers, bufs2, fbufs2,
+         total) = outs
+        st = self.stats
+        _charge_buffers(st, self._n_buffers, b, args, outs)
         for si in seg_cer:
             self._buffers[si] = bufs2[si]
         for si in seg_fail:
             self._fail_buffers[si] = fbufs2[si]
         if self.fail_debug_hook is not None:
             self.fail_debug_hook(self)
-        st = self.stats
         st.device_steps += 1
         st.supersteps += 1
         st.tiles += n_real
@@ -297,11 +299,11 @@ class ShardedTileScheduler(_ShardLoopBase, TileScheduler):
         self._parts_j = [jnp.asarray(p) for p in parts]
         self._part_counts = [int(c) if root_alive else 0 for c in counts]
         self._nil_part = jnp.zeros((plan.root_words,), jnp.uint32)
-        # replicate the adjacency tables / candidate masks across the mesh
-        # once — without this every dispatch would re-broadcast them
-        rep = NamedSharding(mesh, P())
-        self._tables = jax.device_put(eng.tables, rep)
-        self._masks = jax.device_put(eng.masks, rep)
+        # replicate the packed tables and masks (one buffer per word
+        # width) across the mesh once — without this every dispatch would
+        # re-broadcast them; the ring records above are lane-stacked
+        self._groups = jax.device_put(eng.packed.groups,
+                                      NamedSharding(mesh, P()))
 
     def _merge(self, b: int):
         return self._merge_fn(b)
@@ -378,7 +380,7 @@ class ShardedTileScheduler(_ShardLoopBase, TileScheduler):
             # readback timing differs (see scheduler._sync_inflight)
             b = stack[-1][0]
             first = self._dispatch(b, self._fill_lanes(b, stack, pending),
-                                   self._tables, self._masks)
+                                   self._groups)
             if not overlap:
                 _sync_inflight(st, [first])
             inflight = [first]
@@ -386,8 +388,7 @@ class ShardedTileScheduler(_ShardLoopBase, TileScheduler):
                           or st.device_steps < max_steps):
                 b2 = stack[-1][0]
                 second = self._dispatch(
-                    b2, self._fill_lanes(b2, stack, pending),
-                    self._tables, self._masks)
+                    b2, self._fill_lanes(b2, stack, pending), self._groups)
                 if not overlap:
                     _sync_inflight(st, [second])
                 inflight.append(second)
@@ -423,6 +424,7 @@ class ShardedSuperbatchScheduler(_ShardLoopBase, SuperbatchScheduler):
         super().__init__(plans, **kw)
         self.mesh = mesh
         self.n_shards = S = int(mesh.devices.size)
+        self._n_buffers: dict = {}       # boundary -> leaves per call
         self._buffers = {
             si: jax.tree.map(lambda x: jnp.stack([x] * S), buf)
             for si, buf in self._buffers.items()}

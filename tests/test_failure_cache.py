@@ -28,6 +28,7 @@ import pytest
 from strategies import HAS_HYPOTHESIS, batch_workload, fig1_pair, random_pair
 
 from repro.api import Dataset, Matcher, MatchOptions
+from repro.core.scheduler import _pack_fail, _unpack_fail
 
 MULTI = len(jax.devices()) > 1
 needs_devices = pytest.mark.skipif(
@@ -244,9 +245,10 @@ def test_poisoned_keys_never_change_counts():
                                       use_failure_cache=False))
 
     def mutate(s):
-        for si, buf in s._fail_buffers.items():
-            s._fail_buffers[si] = {
-                **buf, "keys": jnp.full_like(buf["keys"], -7777)}
+        for si, rec in s._fail_buffers.items():
+            buf = _unpack_fail(rec)
+            s._fail_buffers[si] = _pack_fail({
+                **buf, "keys": jnp.full_like(buf["keys"], -7777)})
 
     sched, calls = _install_poison(m, query, opts, mutate)
     try:
@@ -272,10 +274,11 @@ def test_poisoned_hash_and_valid_never_change_counts():
                                       use_failure_cache=False))
 
     def mutate(s):
-        for si, buf in s._fail_buffers.items():
-            s._fail_buffers[si] = {
+        for si, rec in s._fail_buffers.items():
+            buf = _unpack_fail(rec)
+            s._fail_buffers[si] = _pack_fail({
                 **buf, "hash": jnp.full_like(buf["hash"], 777),
-                "valid": jnp.ones_like(buf["valid"])}
+                "valid": jnp.ones_like(buf["valid"])})
 
     sched, calls = _install_poison(m, query, opts, mutate)
     try:

@@ -113,7 +113,7 @@ def test_pad_copy_bytes_follow_the_formula(tmp_path, pair):
                 if stage[0] != "extend":
                     continue
                 for (_, u) in stage[1].bk_pairs:
-                    s, w = eng.tables[f"{u}:{stage[1].vertex}"].shape
+                    s, w = eng.plan.tables[(u, stage[1].vertex)].shape
                     assert w % LANES
                     total += s * (-(-w // LANES) * LANES) * 4
         return total
