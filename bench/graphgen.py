@@ -1,15 +1,10 @@
-"""The benchmark's data: a co-authorship graph and its random-walk queries.
+"""The benchmark's data: a configuration's graph and its random-walk queries.
 
-The graph (`coauthor_graph`) is made as a co-authorship network is: papers
-whose authors all become pairwise neighbours. Each author is on at least one
-paper; the other author slots go by a productivity weight i^-alpha over
-randomly permuted ids, and paper sizes follow P(s) ~ s^-beta on
-2..max_paper_size. Edges are kept in the order their papers come, unique,
-up to the configuration's edge count, so the graph has exactly the
-configuration's vertices and edges. Vertex labels are uniform, as the
-study's protocol gives a graph that has none. The configuration states
-every parameter; `bench/configs/` says which published statistics they
-were fitted to.
+The graph (`data_graph`) is made by the graph model that the
+configuration's "graph" section names: `bench/graphs/<model>.py`, whose
+`graph(spec)` returns unique edges u < v as an (m, 2) int64 array and the
+vertices' labels as an (n,) int32 array. This module adds the sorted CSR
+that the queries and the reference walk.
 
 The queries (`draw_pool`) follow the study's recipe: a random walk from a
 uniform start vertex until it has visited `size` distinct vertices, and the
@@ -23,11 +18,16 @@ the traffic (PERF.md says why the graph is not renamed per seed).
 from __future__ import annotations
 
 import dataclasses
+from pathlib import Path
 
 import numpy as np
 
-__all__ = ["DataGraph", "build_csr", "coauthor_graph", "data_graph",
-           "walk_query", "draw_pool"]
+from bench import lookup
+
+__all__ = ["DataGraph", "build_csr", "data_graph", "walk_query",
+           "draw_pool"]
+
+ROOT = Path(__file__).resolve().parents[1]   # the checkout's root
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,42 +49,6 @@ class DataGraph:
         return np.diff(self.indptr)
 
 
-def coauthor_graph(n: int, m: int, author_slots: int, alpha: float,
-                   beta: float, max_paper_size: int,
-                   seed: int) -> np.ndarray:
-    """(m, 2) unique edges u < v of the co-authorship model (module doc),
-    in the order their papers come."""
-    rng = np.random.default_rng(seed)
-    w = np.arange(1, n + 1, dtype=np.float64) ** (-alpha)
-    w /= w.sum()
-    perm = rng.permutation(n)
-    slots = np.concatenate([rng.permutation(n),
-                            perm[rng.choice(n, author_slots - n, p=w)]])
-    slots = slots[rng.permutation(author_slots)]
-    ks = np.arange(2, max_paper_size + 1)
-    p = ks.astype(np.float64) ** (-beta)
-    sizes = rng.choice(ks, size=author_slots // 2, p=p / p.sum())
-    sizes = sizes[:np.searchsorted(np.cumsum(sizes), author_slots, "right")]
-    starts = np.cumsum(sizes) - sizes
-    paper, us, vs = [], [], []
-    for s in np.unique(sizes):
-        at = np.flatnonzero(sizes == s)
-        iu, ju = np.triu_indices(s, 1)
-        paper.append(np.repeat(at, len(iu)))
-        us.append(slots[(starts[at, None] + iu).ravel()])
-        vs.append(slots[(starts[at, None] + ju).ravel()])
-    order = np.argsort(np.concatenate(paper), kind="stable")
-    u, v = np.concatenate(us)[order], np.concatenate(vs)[order]
-    keep = u != v
-    lo = np.minimum(u[keep], v[keep]).astype(np.int64)
-    hi = np.maximum(u[keep], v[keep]).astype(np.int64)
-    keys, first = np.unique(lo * n + hi, return_index=True)
-    if len(keys) < m:
-        raise ValueError(f"the model gives {len(keys)} edges, under {m}")
-    keys = np.sort(keys[np.argsort(first)][:m])
-    return np.stack([keys // n, keys % n], 1)
-
-
 def build_csr(n: int, edges: np.ndarray):
     """Sorted CSR (both directions) of unique undirected edges."""
     src = np.concatenate([edges[:, 0], edges[:, 1]])
@@ -96,18 +60,15 @@ def build_csr(n: int, edges: np.ndarray):
     return indptr, dst[order].astype(np.int32)
 
 
-def data_graph(spec: dict) -> DataGraph:
-    """The configuration's graph; `spec` is its "graph" section."""
-    n, n_labels = int(spec["vertices"]), int(spec["labels"])
-    edges = coauthor_graph(n, int(spec["edges"]), int(spec["author_slots"]),
-                           float(spec["productivity_alpha"]),
-                           float(spec["paper_size_beta"]),
-                           int(spec["max_paper_size"]), int(spec["seed"]))
-    labels = np.random.default_rng([int(spec["seed"]), 1]).integers(
-        0, n_labels, size=n).astype(np.int32)
+def data_graph(spec: dict, root: Path = ROOT) -> DataGraph:
+    """The configuration's graph; `spec` is its "graph" section, whose
+    "model" names the file `<root>/bench/graphs/<model>.py` that makes it
+    (its `graph(spec)` returns the edges and labels)."""
+    edges, labels = lookup.module(root, "graphs", spec["model"]).graph(spec)
+    n = int(spec["vertices"])
     indptr, indices = build_csr(n, edges)
-    return DataGraph(n=n, n_labels=n_labels, edges=edges, labels=labels,
-                     indptr=indptr, indices=indices)
+    return DataGraph(n=n, n_labels=int(spec["labels"]), edges=edges,
+                     labels=labels, indptr=indptr, indices=indices)
 
 
 def walk_query(g: DataGraph, size: int, seed: int):

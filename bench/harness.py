@@ -1,34 +1,57 @@
 """The chip benchmark's harness: one run of one cell.
 
-Everything a cell is made of is data, found by name from `BENCHMARK.json`:
+A cell is made of files found by name from `BENCHMARK.json`:
 
-* the configuration: `configs[].file` (the graph, the query pool, the
-  limit and the guarantees; see `bench/configs/`);
-* the traffic mix: `bench/traffic/<traffic>.json`, read by the one
-  generator `bench/traffic.py`;
+* the configuration: `configs[].file`, data (the graph's parameters, the
+  query pool, the limit and the guarantees; see `bench/configs/`);
+* the graph model: `bench/graphs/<model>.py`, named by the configuration's
+  "graph" section, whose `graph(spec)` makes the edges and labels
+  (`bench/graphgen.py` builds the rest);
+* the traffic mix: `bench/traffic/<traffic>.json`, data, whose "loop"
+  names the request loop;
+* the request loop: `bench/loops/<loop>.py` (below);
 * each metric: `bench/metrics/<name>.py`, a reader `read(run)` that returns
   a number or None (nothing to read in this cell).
 
-A run builds the configuration's graph (`bench/graphgen.py`) and hands the
-program only that graph and the pool's queries; `--seed` drives the
-traffic. Set-up warms every program shape the traffic can reach; then the
-window runs for `--seconds` and nothing may compile in it. With `--trace 1`
-the first seconds of the window are profiled (`bench/trace_reduce.py`) and
-the per-layer metrics are printed instead of the end-to-end ones. After the
+A cell on a new deployment is new files alone, and edits none that is
+here: its configuration and traffic mix always, and a graph model, a loop
+or a metric only where none here fits.
+
+A request loop exports `run(run, config, mix, seed, ds, pool, tw, events,
+t_start)`: `run` the `Run` to fill, `config` and `mix` the cell's data,
+`seed` the run's `--seed`, `ds` the program's `Dataset` of the graph,
+`pool` the pool's queries as the program's graphs, `tw` the
+`TraceWindow`, `events` the `JaxEvents`, `t_start` the process's start on
+`time.perf_counter`. It warms up every program shape its traffic reaches,
+sets `run.setup_s`, calls `tw.start()` as the window opens and
+`tw.tick(elapsed)` as it goes, and fills `window_s`, `latencies_ms`,
+`attempted`, `completed`, `failed`, `engines` and `counters` (numbers of
+its own go into `counters` under their own names). It returns the answers,
+one `(pool index, count, timed_out)` each, and the `events.snapshot()` at
+the window's start and at its end. It may also export `checks(run,
+answers)`, which returns further `{name: {"value", "limit"}}` numbers
+compared; they join the reference's in `checks` and in `correct`.
+
+A run builds the configuration's graph and hands the program only that
+graph and the pool's queries; `--seed` drives the traffic. Set-up warms
+every program shape the traffic can reach; then the window runs for
+`--seconds` and nothing may compile in it. With `--trace 1` the first
+seconds of the window are profiled (`bench/trace_reduce.py`) and the
+per-layer metrics are printed instead of the end-to-end ones. After the
 window every answer is checked against the plain reference
 (`bench/reference.py`).
 
 The last line of standard output is one JSON object (`correct`, `attempted`,
 `failed`, `metrics`, `device`, with `--trace 1` also `breakdown`, and last
 `checks`: each number compared with its limit). The checks are also the
-last lines of standard error.
+last lines of standard error. An unknown cell, model or loop, or a missing
+chip, exits with code 2 and prints no result.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
 import gc
-import importlib.util
 import json
 import os
 import shutil
@@ -39,7 +62,8 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import graphgen, reference, trace_reduce, traffic
+from bench import graphgen, lookup, reference, trace_reduce
+from bench.lookup import CellError
 
 __all__ = ["main", "Run", "load_cell", "metric_names"]
 
@@ -49,10 +73,6 @@ TRACE_SECONDS = 4.0        # profiled part of the window in a --trace 1 run
 EVENTS = {"/jax/core/compile/backend_compile_duration": "backend",
           "/jax/compilation_cache/cache_retrieval_time_sec": "loads",
           "/jax/core/compile/jaxpr_to_mlir_module_duration": "lowerings"}
-
-
-class CellError(Exception):
-    """The cell cannot run here: unknown name, missing file, no chip."""
 
 
 @dataclasses.dataclass
@@ -73,7 +93,8 @@ class Run:
 
 # ------------------------------------------------------------------- lookup
 def load_cell(root: Path, name: str) -> tuple[dict, dict, dict, dict]:
-    """(manifest, cell, configuration, traffic mix) of workload `name`."""
+    """(manifest, cell, configuration, traffic mix) of workload `name`,
+    once its graph model's and its loop's files are found."""
     manifest = json.loads((root / "BENCHMARK.json").read_text())
     cells = {c["name"]: c for c in manifest["workloads"]}
     if name not in cells:
@@ -83,6 +104,8 @@ def load_cell(root: Path, name: str) -> tuple[dict, dict, dict, dict]:
     config = json.loads((root / cfg["file"]).read_text())
     mix = json.loads((root / "bench" / "traffic"
                       / f"{cell['traffic']}.json").read_text())
+    lookup.path(root, "graphs", config["graph"]["model"])
+    lookup.path(root, "loops", mix["loop"])
     return manifest, cell, config, mix
 
 
@@ -93,11 +116,7 @@ def metric_names(manifest: dict, cell: str, per_layer: bool) -> list[dict]:
 
 
 def read_metric(root: Path, name: str, run: Run):
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name}", root / "bench" / "metrics" / f"{name}.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(run)
+    return lookup.module(root, "metrics", name).read(run)
 
 
 # -------------------------------------------------------------------- jax
@@ -175,12 +194,12 @@ def span(name: str):
 
 
 # ------------------------------------------------------------------ inputs
-def build_inputs(config: dict):
+def build_inputs(config: dict, root: Path):
     """The configuration's graph, the program's Dataset of it, and the
     pool's queries as the program's graphs."""
     from repro.api import Dataset
     from repro.core.graph import build_graph
-    g = graphgen.data_graph(config["graph"])
+    g = graphgen.data_graph(config["graph"], root)
     ds = Dataset.from_edges(g.n, g.edges, g.labels, n_labels=g.n_labels,
                             name=config["name"])
     pool = [build_graph(len(q["labels"]), np.asarray(q["edges"]),
@@ -195,50 +214,11 @@ def match_options(config: dict):
                         **config.get("match_options", {}))
 
 
-def _add_stats(acc: dict, stats) -> None:
+def add_stats(acc: dict, stats) -> None:
+    """Adds a request's numeric stats into a run's counters."""
     for k, v in dataclasses.asdict(stats).items():
         if isinstance(v, (int, float)):
             acc[k] = acc.get(k, 0) + v
-
-
-# ------------------------------------------------------------ closed loop
-def closed_loop(run: Run, config, mix, seed, ds, pool, tw, events,
-                t_start):
-    """One client: `Matcher.count` back to back for `seconds`."""
-    from repro.api import Matcher
-    m = Matcher(ds, match_options(config))
-    with span("bench.warmup"):
-        for k, q in enumerate(pool):
-            t = time.perf_counter()
-            m.count(q)
-            m.count(q)
-            print(f"bench: warm-up {config['pool'][k].get('set', k)} "
-                  f"{time.perf_counter() - t:.3f} s", file=sys.stderr)
-    answers = []
-    draws = traffic.closed_requests(mix, len(pool), seed)
-    ev0 = events.snapshot()
-    run.setup_s = time.perf_counter() - t_start
-    tw.start()
-    t0 = time.perf_counter()
-    end = t0
-    while end - t0 < run.seconds:
-        k = next(draws)
-        with span("bench.count"):
-            t = time.perf_counter()
-            out = m.count(pool[k])
-            end = time.perf_counter()
-        run.latencies_ms.append((end - t) * 1e3)
-        answers.append((k, out.count, out.timed_out))
-        run.engines[out.engine] = run.engines.get(out.engine, 0) + 1
-        _add_stats(run.counters, out.stats)
-        tw.tick(end - t0)
-    run.window_s = end - t0
-    run.attempted = run.completed = len(answers)
-    run.failed = sum(1 for a in answers if a[2])
-    return answers, ev0, events.snapshot()
-
-
-LOOPS = {"closed": closed_loop}
 
 
 # ------------------------------------------------------------- correctness
@@ -301,10 +281,11 @@ def main(argv, *, root, t_start=None, require_tpu=True) -> int:
     run = Run(seconds=args.seconds)
     tmp = tempfile.mkdtemp(prefix="bench-")
     try:
-        g, ds, pool = build_inputs(config)
+        loop = lookup.module(root, "loops", mix["loop"])
+        g, ds, pool = build_inputs(config, root)
         tw = TraceWindow(bool(args.trace), min(TRACE_SECONDS, args.seconds),
                          tmp)
-        answers, ev0, ev1 = LOOPS[mix["loop"]](
+        answers, ev0, ev1 = loop.run(
             run, config, mix, args.seed, ds, pool, tw, events, t_start)
         tw.stop()
         device["memory_peak_bytes"] = peak_bytes(devs)
@@ -312,6 +293,8 @@ def main(argv, *, root, t_start=None, require_tpu=True) -> int:
         del ds, pool
         gc.collect()
         checks = check(answers, config, g)
+        if hasattr(loop, "checks"):
+            checks.update(loop.checks(run, answers))
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
     print(f"bench: setup_s={run.setup_s:.3f} window_s={run.window_s:.3f} "

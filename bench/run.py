@@ -2,8 +2,8 @@
 
     python bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-The cell is an entry of `workloads` in `BENCHMARK.json`; its configuration
-and traffic mix are data files under `bench/` (see `bench/harness.py`).
+The cell is an entry of `workloads` in `BENCHMARK.json`; the files it is
+made of lie under `bench/` (see `bench/harness.py`).
 Without a TPU the run exits non-zero and prints no result.
 """
 import time

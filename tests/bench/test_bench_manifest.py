@@ -2,6 +2,7 @@
 every file a cell is made of by name."""
 import json
 import re
+import shutil
 from pathlib import Path
 
 import pytest
@@ -85,7 +86,9 @@ def test_each_cell_is_whole(cell):
     assert "setup_s" in e2e and len(e2e) >= 2
     assert any(reported(m, cell["name"]) for m in MAN["per_layer"])
     _, _, config, mix = harness.load_cell(ROOT, cell["name"])
-    assert mix["loop"] in harness.LOOPS
+    assert (ROOT / "bench" / "loops" / f"{mix['loop']}.py").is_file()
+    assert (ROOT / "bench" / "graphs"
+            / f"{config['graph']['model']}.py").is_file()
     assert config["pool"] and config["limit"] > 0
 
 
@@ -116,14 +119,39 @@ def test_a_cell_of_new_files_is_found(tiny_root):
     names = [m["name"] for m in harness.metric_names(man, "tiny.count",
                                                      per_layer=True)]
     assert "device_idle.count" in names
+    man, cell, config, mix = harness.load_cell(tiny_root, "tiny-ring.replay")
+    assert mix["loop"] == "replay" and config["graph"]["model"] == \
+        "cliquering"
+    assert [m["name"] for m in harness.metric_names(
+        man, "tiny-ring.replay", per_layer=True)] == ["replayed.tiny"]
     with pytest.raises(harness.CellError):
         harness.load_cell(tiny_root, "no.such")
 
 
 def test_a_cell_of_new_files_runs(tiny_root, run_cell):
-    rc, out = run_cell(tiny_root, "tiny.count")
-    assert rc == 0 and out["correct"] is True
-    assert {"queries_per_s", "query_p95_ms", "setup_s"} <= set(out["metrics"])
+    rc, out = run_cell(tiny_root, "tiny-ring.replay")
+    assert rc == 0 and out["correct"] is True, out["checks"]
+    assert set(out["metrics"]) == {"replay_mean_ms", "setup_s"}
+    pool = harness.load_cell(tiny_root, "tiny-ring.replay")[2]["pool"]
+    assert out["attempted"] == len(pool) == 4
+    assert list(out["checks"]) == ["wrong_counts", "not_asked_once"]
+    assert out["checks"]["not_asked_once"] == {"value": 0, "limit": 0}
+
+
+@pytest.mark.parametrize("part", ["model", "loop"])
+def test_an_unknown_model_or_loop_gives_no_result(tiny_root, tmp_path,
+                                                  run_cell, part):
+    root = tmp_path / "root"
+    shutil.copytree(tiny_root, root)
+    name, section = {"model": ("configs/tiny.json", "graph"),
+                     "loop": ("traffic/tiny_mix.json", None)}[part]
+    path = root / "bench" / name
+    data = json.loads(path.read_text())
+    (data[section] if section else data)[part] = "no_such"
+    path.write_text(json.dumps(data))
+    with pytest.raises(harness.CellError, match="no_such"):
+        harness.load_cell(root, "tiny.count")
+    assert run_cell(root, "tiny.count") == (2, None)
 
 
 def test_no_tpu_means_no_result(tmp_path, capsys):

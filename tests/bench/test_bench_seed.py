@@ -12,7 +12,7 @@ import jax
 import numpy as np
 import pytest
 
-from bench import graphgen, harness, reference, traffic
+from bench import graphgen, harness, lookup, reference
 
 SPEC = {"model": "coauthorship", "vertices": 20000, "edges": 66000,
         "labels": 15, "author_slots": 46000, "productivity_alpha": 0.4,
@@ -88,7 +88,8 @@ def test_seeds_share_the_graph_and_vary_the_traffic(config):
     a, b = graphgen.data_graph(SPEC), graphgen.data_graph(SPEC)
     assert np.array_equal(a.edges, b.edges)
     assert np.array_equal(a.labels, b.labels)
-    c0, c1 = (traffic.closed_requests({}, 4, s) for s in SEEDS)
+    closed = lookup.module(graphgen.ROOT, "loops", "closed")
+    c0, c1 = (closed.requests({}, 4, s) for s in SEEDS)
     r0, r1 = [next(c0) for _ in range(8)], [next(c1) for _ in range(8)]
     assert r0 != r1 and sorted(r0) == sorted(r1) == [0, 0, 1, 1, 2, 2, 3, 3]
 
